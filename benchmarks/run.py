@@ -27,6 +27,9 @@ def main(argv=None) -> None:
     argv = [a for a in argv if a != "--tiny"]
     steps = int(os.environ.get("REPRO_BENCH_STEPS", "100"))
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
+
     from benchmarks import (table1_lm_quality, table2_vlm_overfit,
                             table3_memory, table4_time, table5_convergence,
                             roofline, serving_bench)

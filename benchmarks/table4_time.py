@@ -64,6 +64,7 @@ Row schema and regeneration contract: docs/BENCHMARKS.md.
 from __future__ import annotations
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -212,14 +213,10 @@ def _time_overlap(cfg, params, calib, label: str, repeats: int = 3) -> list:
 _EXPERT_MESH = "1x2x4"  # DxMxE: rows over model=2, expert lanes over E=4
 
 
-def _expert_cell_main() -> None:
-    """Subprocess entry for the expert-sharded MoE cell: quantize the MoE
-    bench config with ``quant.mesh=_EXPERT_MESH`` under the overlap
-    scheduler and print the bench row as JSON on the last stdout line.
-
-    Runs out-of-process because the expert mesh axis needs a forced
-    multi-device host platform, and ``XLA_FLAGS`` only takes effect
-    before jax initializes (the parent keeps the single real device)."""
+def _expert_cell() -> dict:
+    """The expert-sharded MoE cell: quantize the MoE bench config with
+    ``quant.mesh=_EXPERT_MESH`` under the overlap scheduler; returns the
+    bench row."""
     cfg = bench_config("olmoe-1b-7b")
     cfg.quant.batched_executor = True
     cfg.quant.pipeline = "overlap"
@@ -231,7 +228,7 @@ def _expert_cell_main() -> None:
     _, rep = quantize_model(cfg, params, calib)
     cold = time.perf_counter() - t0
     wall, best = _timed_repeats(cfg, params, calib, repeats=2)
-    print(json.dumps({
+    return {
         "config": f"moe-{cfg.model.name}", "impl": "xla",
         "pipeline": "overlap", "quant_mesh": _EXPERT_MESH,
         "cold_s": round(cold, 2), "warm_s": round(wall, 2),
@@ -239,14 +236,35 @@ def _expert_cell_main() -> None:
         "stage1_s": round(best[1], 3), "stage2_s": round(best[2], 3),
         "xla_ops": None, "xla_ops_s2": None,
         "pipeline_stats": dict(rep.pipeline_stats),
-    }))
+    }
+
+
+def _expert_cell_main() -> None:
+    """Subprocess entry: the cell's row as JSON on the last stdout line."""
+    print(json.dumps(_expert_cell()))
 
 
 def _time_expert_sharded(label: str) -> list:
     """The expert-parallel A/B cell for the MoE row (see
-    :func:`_expert_cell_main`). Skipped under ``REPRO_BENCH_PIPELINE``
-    for the same reason as :func:`_time_overlap`."""
+    :func:`_expert_cell`). Skipped under ``REPRO_BENCH_PIPELINE`` for the
+    same reason as :func:`_time_overlap`.
+
+    Runs in this process when it already sees enough devices. Otherwise,
+    on the CPU, a child with a forced multi-device host platform runs it
+    (``XLA_FLAGS`` only takes effect before jax initializes, and the
+    parent keeps its single device). On an accelerator the cell is
+    skipped instead: this process holds the chip, so a child that needs
+    it would fail or hang."""
     if os.environ.get("REPRO_BENCH_PIPELINE"):
+        return []
+    need = math.prod(int(a) for a in _EXPERT_MESH.split("x"))
+    if jax.device_count() >= need:
+        cell = _expert_cell()
+        assert cell["config"] == label, (cell["config"], label)
+        return [cell]
+    if jax.default_backend() != "cpu":
+        print(f"  [table4] skipping the {_EXPERT_MESH} expert-sharded "
+              f"cell: needs {need} devices, have {jax.device_count()}")
         return []
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -17,7 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core import hessian as hess
 from repro.core.gptq import gptq_quantize
@@ -32,7 +32,7 @@ U = hess.cholesky_inverse_upper(Hd)
 
 res1 = gptq_quantize(W, U, bits=4, group_size=128, blocksize=128)
 
-mesh = jax.make_mesh((8,), ("rows",))
+mesh = jax.make_mesh((8,), ("rows",), axis_types=(AxisType.Auto,))
 shard = NamedSharding(mesh, P("rows", None))
 rep = NamedSharding(mesh, P(None, None))
 W_sh = jax.device_put(W, shard)

@@ -66,13 +66,22 @@ def run_serve(params: str, extra) -> None:
 
 
 def load_leaves(path: str):
+    """Artifact leaves as host arrays. This process only starts children
+    that need the device, so it must never initialise a JAX backend itself
+    (on an accelerator it would then hold the chip the next child needs):
+    artifacts hold numpy leaves, and the check below keeps it that way."""
     import jax                      # registers QuantizedTensor pytree nodes
     import numpy as np
     import repro                    # noqa: F401
     import repro.kernels.ops        # noqa: F401
+    from jax._src import xla_bridge
     with open(path, "rb") as f:
         tree = pickle.load(f)
-    return [np.asarray(l) for l in jax.tree_util.tree_leaves(tree)]
+    leaves = [np.asarray(l) for l in jax.tree_util.tree_leaves(tree)]
+    if xla_bridge.backends_are_initialized():
+        raise SystemExit("resume_smoke: loading an artifact initialised a "
+                         "JAX backend in the parent process")
+    return leaves
 
 
 def main() -> None:

@@ -110,6 +110,10 @@ class QuantReport:
     # saw fewer instances than the batch implies
     moe_capacity_dropped: Dict[str, int] = dataclasses.field(
         default_factory=dict)
+    # sharded group execution: groups counted by how many distinct shards
+    # each stage's output held on the mesh before the gather
+    # ({"stage1_shards=4": n, ...}) — shows the work really spread
+    mesh_spread: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def summary(self) -> str:
         n = len(self.linears)
@@ -123,6 +127,13 @@ class QuantReport:
 # ---------------------------------------------------------------------------
 # Plan structure
 # ---------------------------------------------------------------------------
+
+def _n_shards(a: jax.Array) -> int:
+    """Distinct index slices of ``a`` over its devices (replicas count
+    once)."""
+    idx = a.sharding.devices_indices_map(a.shape).values()
+    return len({tuple((s.start, s.stop) for s in i) for i in idx})
+
 
 GroupKey = Tuple[int, int, int, int, int, int, bool]
 # (out, in, n_last, group_size, blocksize, bits, symmetric)
@@ -276,13 +287,13 @@ def _lane_hessians(m: PlanMember) -> hess.HessianState:
 # module-level cache means the q/k/v/o group of layer 7 reuses the entry
 # layer 0 compiled (first half of the ROADMAP "cross-layer plan batching"
 # item; the pipelined-capture half remains open).  Each cached entry
-# additionally FUSES its stage into one dispatch: stage 1 runs damp +
-# Cholesky + GPTQ sweep (+ the RTN fallback lane when the group has
-# starved members) inside a single jit, stage 2 wraps the RPIQ refinement
-# with its statics bound.  Sharded stage-1 entries close over the mesh
-# (the sweep goes through gptq_block_sharded's shard_map), so the mesh
-# component of the key is what keeps single-device and sharded entries —
-# or two different meshes — from aliasing.
+# binds its stage's statics: stage 1 is two jits, damp + Cholesky and then
+# the GPTQ sweep (+ the RTN fallback lane when the group has starved
+# members), stage 2 wraps the RPIQ refinement.  Sharded stage-1 entries
+# close over the mesh (the sweep goes through gptq_block_sharded's
+# shard_map), so the mesh component of the key is what keeps
+# single-device and sharded entries — or two different meshes — from
+# aliasing.
 # ---------------------------------------------------------------------------
 
 _EXEC_CACHE: Dict[Tuple, Callable] = {}
@@ -346,12 +357,20 @@ def _make_stage1(qc: QuantConfig, impl: str, with_rtn: bool,
     bits, group_size = qc.bits, qc.group_size
     blocksize, symmetric = qc.blocksize, qc.symmetric
 
-    def fn(w, H, percdamp):
-        # inputs arrive committed to the group sharding (lane-local H,
-        # (lane, row)-tiled w); damp + Cholesky partition along with them,
-        # so each lane factors where its rows live.
+    # damp + Cholesky are a program of their own, the same with or without
+    # a mesh: XLA rounds the factor differently with what else the program
+    # holds (a v5e fusing it with the in=3072 sweep flips 15 % of the codes
+    # against a standalone factor), so a factor fused into the sharded
+    # sweep's program would part from the unsharded one.
+    @jax.jit
+    def factor(H, percdamp):
+        # H arrives committed to the group sharding (lane-local), so each
+        # lane factors where its rows live.
         hd = hess.damped(hess.HessianState(H, None), percdamp)
-        u = hess.cholesky_inverse_upper(hd)
+        return hd, hess.cholesky_inverse_upper(hd)
+
+    @jax.jit
+    def sweep(w, u):
         if gshard is None:
             res1 = gptq_quantize_batched(w, u, bits=bits,
                                          group_size=group_size,
@@ -364,9 +383,13 @@ def _make_stage1(qc: QuantConfig, impl: str, with_rtn: bool,
                 blocksize=blocksize, symmetric=symmetric, impl=impl))
         rtn = rtn_quantize_batched(w, bits=bits, group_size=group_size,
                                    symmetric=symmetric) if with_rtn else None
-        return hd, res1, rtn
+        return res1, rtn
 
-    return jax.jit(fn)
+    def fn(w, H, percdamp):
+        hd, u = factor(H, percdamp)
+        return (hd, *sweep(w, u))
+
+    return fn
 
 
 def _make_stage2(qc: QuantConfig, impl: str,
@@ -529,6 +552,11 @@ def _execute_group_batched(qc: QuantConfig, group: QuantGroup,
         zeros = jnp.where(sel, rtn.zeros, zeros)
 
     if gshard is not None:
+        for stage, out in (("stage1", res1.w_q),
+                           ("stage2", res2.w_q if do_rpiq else None)):
+            if out is not None:
+                key = f"{stage}_shards={_n_shards(out)}"
+                report.mesh_spread[key] = report.mesh_spread.get(key, 0) + 1
         # gather the group's artifacts off the mesh: the scatter feeds the
         # (single-device) propagate forward, and leaving mesh-committed
         # leaves in the param tree would silently partition that forward —

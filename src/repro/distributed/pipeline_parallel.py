@@ -29,7 +29,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_forward(mesh: Mesh, stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -84,11 +83,11 @@ def gpipe_forward(mesh: Mesh, stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     other_axes = [a for a in mesh.axis_names if a != axis]
     pspec = P(axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(*([None] * x.ndim))),
         out_specs=P(*([None] * x.ndim)),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)
 
 

@@ -53,9 +53,11 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK_OUT = 128     # row tile (MXU/lane aligned)
 
 
-def _iota1d(n: int) -> jax.Array:
-    """1D int32 iota via 2D broadcasted_iota (TPU: 1D iota is invalid)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
+def _lane_iota(n: int) -> jax.Array:
+    """(1, n) int32 column ids. Every mask and per-row quantity in the
+    kernel stays 2-D — (1, n) rows and (out_t, 1) columns — because Mosaic
+    cannot relayout a 1-D boolean into a column (``[:, None]`` on a mask)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
 
 def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
@@ -67,9 +69,10 @@ def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
     n_groups = n_blocks * gpb
     qmax = 2.0 ** bits - 1.0
 
-    cols_bs = _iota1d(blocksize)                  # (bs,) in-block column ids
-    cols_in = _iota1d(in_dim)                     # (Cin,) absolute columns
-    groups = _iota1d(n_groups)                    # (n_groups,)
+    cols_bs = _lane_iota(blocksize)               # (1, bs) in-block columns
+    rows_bs = jax.lax.broadcasted_iota(jnp.int32, (blocksize, 1), 0)
+    cols_in = _lane_iota(in_dim)                  # (1, Cin) absolute columns
+    groups = _lane_iota(n_groups)                 # (1, n_groups)
     eye_bs = (jax.lax.broadcasted_iota(jnp.int32, (blocksize, blocksize), 0)
               == jax.lax.broadcasted_iota(jnp.int32,
                                           (blocksize, blocksize), 1))
@@ -82,11 +85,12 @@ def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
         wb0 = wq_ref[0, :, pl.ds(c1, blocksize)]            # (out_t, bs)
         u_rows = u_ref[0, pl.ds(c1, blocksize), :]          # (bs, Cin)
         ub = u_ref[0, pl.ds(c1, blocksize), pl.ds(c1, blocksize)]
-        diag = jnp.sum(jnp.where(eye_bs, ub, 0.0), axis=1)  # (bs,) exact
+        # (1, bs) diagonal — exact: one nonzero per column sum
+        diag = jnp.sum(jnp.where(eye_bs, ub, 0.0), axis=0, keepdims=True)
 
         def col_step(j, cc):
             wb, errb, scale, zero, sfull, zfull = cc
-            onehot = cols_bs == j                            # (bs,)
+            onehot = cols_bs == j                            # (1, bs)
 
             def refresh(args):
                 wb, scale, zero, sfull, zfull = args
@@ -94,23 +98,23 @@ def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
                 # non-group columns from the max/min reductions (order-free)
                 gmask = (cols_bs // group_size) == (j // group_size)
                 if symmetric:
-                    absmax = jnp.max(jnp.where(gmask[None, :], jnp.abs(wb),
-                                               0.0), axis=1)
+                    absmax = jnp.max(jnp.where(gmask, jnp.abs(wb), 0.0),
+                                     axis=1, keepdims=True)
                     scale = jnp.maximum(absmax / (2.0 ** (bits - 1) - 1),
                                         1e-8)
                     zero = jnp.zeros_like(scale)
                 else:
                     wmax = jnp.maximum(jnp.max(
-                        jnp.where(gmask[None, :], wb, -jnp.inf), axis=1),
-                        0.0)
+                        jnp.where(gmask, wb, -jnp.inf), axis=1,
+                        keepdims=True), 0.0)
                     wmin = jnp.minimum(jnp.min(
-                        jnp.where(gmask[None, :], wb, jnp.inf), axis=1),
-                        0.0)
+                        jnp.where(gmask, wb, jnp.inf), axis=1,
+                        keepdims=True), 0.0)
                     scale = jnp.maximum((wmax - wmin) / qmax, 1e-8)
                     zero = jnp.clip(jnp.round(-wmin / scale), 0.0, qmax)
-                gsel = (groups == ((c1 + j) // group_size))[None, :]
-                sfull = jnp.where(gsel, scale[:, None], sfull)
-                zfull = jnp.where(gsel, zero[:, None], zfull)
+                gsel = groups == ((c1 + j) // group_size)    # (1, n_groups)
+                sfull = jnp.where(gsel, scale, sfull)
+                zfull = jnp.where(gsel, zero, zfull)
                 return scale, zero, sfull, zfull
 
             # group-entry refresh only (the cond skips the reductions on
@@ -121,25 +125,28 @@ def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
                 (wb, scale, zero, sfull, zfull))
 
             # one-hot extraction is exact: a single nonzero per reduction
-            wcol = jnp.sum(jnp.where(onehot[None, :], wb, 0.0), axis=1)
-            d = jnp.sum(jnp.where(onehot, diag, 0.0))
+            wcol = jnp.sum(jnp.where(onehot, wb, 0.0), axis=1,
+                           keepdims=True)                    # (out_t, 1)
+            d = jnp.sum(jnp.where(onehot, diag, 0.0), axis=1,
+                        keepdims=True)                       # (1, 1)
             if symmetric:
                 lo, hi = -(2.0 ** (bits - 1)), 2.0 ** (bits - 1) - 1
                 q = jnp.clip(jnp.round(wcol / scale), lo, hi) * scale
             else:
                 q = (jnp.clip(jnp.round(wcol / scale) + zero, 0.0, qmax)
                      - zero) * scale
-            err = (wcol - q) / d
-            urow = jnp.sum(jnp.where(onehot[:, None], ub, 0.0), axis=0)
+            err = (wcol - q) / d                             # (out_t, 1)
+            urow = jnp.sum(jnp.where(rows_bs == j, ub, 0.0), axis=0,
+                           keepdims=True)                    # (1, bs)
             mask = (cols_bs > j).astype(jnp.float32)
-            wb = wb - err[:, None] * (urow * mask)[None, :]
-            wb = jnp.where(onehot[None, :], q[:, None], wb)
-            errb = jnp.where(onehot[None, :], err[:, None], errb)
+            wb = wb - err * (urow * mask)
+            wb = jnp.where(onehot, q, wb)
+            errb = jnp.where(onehot, err, errb)
             return wb, errb, scale, zero, sfull, zfull
 
         init = (wb0, jnp.zeros_like(wb0),
-                jnp.zeros((out_t,), jnp.float32),
-                jnp.zeros((out_t,), jnp.float32), sfull, zfull)
+                jnp.zeros((out_t, 1), jnp.float32),
+                jnp.zeros((out_t, 1), jnp.float32), sfull, zfull)
         wb, errb, _, _, sfull, zfull = jax.lax.fori_loop(
             0, blocksize, col_step, init)
 
@@ -147,19 +154,20 @@ def _gptq_block_kernel(w_ref, u_ref, wq_ref, s_ref, z_ref, err_ref, *,
         # shapes as the XLA path so the contraction rounds identically
         tail = (cols_in >= c1 + blocksize).astype(jnp.float32)
         w_full = wq_ref[0]
-        w_full = w_full - jnp.dot(errb, u_rows * tail[None, :],
+        w_full = w_full - jnp.dot(errb, u_rows * tail,
                                   preferred_element_type=jnp.float32)
         wq_ref[0] = w_full
         wq_ref[0, :, pl.ds(c1, blocksize)] = wb
-        return sfull, zfull, err_rows + jnp.sum(errb * errb, axis=1)
+        return sfull, zfull, err_rows + jnp.sum(errb * errb, axis=1,
+                                                keepdims=True)
 
     init = (jnp.zeros((out_t, n_groups), jnp.float32),
             jnp.zeros((out_t, n_groups), jnp.float32),
-            jnp.zeros((out_t,), jnp.float32))
+            jnp.zeros((out_t, 1), jnp.float32))
     sfull, zfull, err_rows = jax.lax.fori_loop(0, n_blocks, block_step, init)
     s_ref[0] = sfull
     z_ref[0] = zfull
-    err_ref[0] = err_rows[:, None]
+    err_ref[0] = err_rows
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group_size",

@@ -16,7 +16,7 @@ multiples and slice back, so kernels keep hard divisibility asserts.
 from __future__ import annotations
 
 import contextlib
-import functools
+import math
 import warnings
 
 import jax
@@ -105,8 +105,10 @@ def _note_fallback(op: str, reason: str) -> None:
 # H += X^T X
 # ---------------------------------------------------------------------------
 
-def hessian_accum(x: jax.Array, *, impl: str = "auto") -> jax.Array:
-    """Gram matrix X^T X with fp32 accumulation. x: (n, d)."""
+def hessian_accum(x: jax.Array, *, impl: str = "auto",
+                  interpret: bool | None = None) -> jax.Array:
+    """Gram matrix X^T X with fp32 accumulation. x: (n, d). ``interpret``
+    overrides the off-TPU interpret default, as in :func:`gptq_block`."""
     if impl == "xla" or (impl == "auto" and not _on_tpu()):
         return ref.hessian_accum_ref(x)
     n, d = x.shape
@@ -115,8 +117,9 @@ def hessian_accum(x: jax.Array, *, impl: str = "auto") -> jax.Array:
     n_pad, d_pad = _round_up(n, block_n), _round_up(d, block_d)
     if (n_pad, d_pad) != (n, d):
         x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-    H = hessian_accum_pallas(x, block_d=block_d, block_n=block_n,
-                             interpret=not _on_tpu())
+    H = hessian_accum_pallas(
+        x, block_d=block_d, block_n=block_n,
+        interpret=(not _on_tpu()) if interpret is None else interpret)
     return H[:d, :d]
 
 
@@ -147,17 +150,55 @@ def w4a16_default_impl(impl: str):
         _W4A16_DEFAULT_IMPL = prev
 
 
-def _w4a16_vmem_bytes(block_m: int, block_n: int, block_k: int) -> int:
-    """Per-cell residency upper bound: x + out tiles f32, packed u8 tile,
-    and the dequantized weight tile (f32) the kernel materializes."""
-    return (4 * (block_m * block_k + block_m * block_n
-                 + 2 * block_n * block_k) + block_n * block_k // 2)
+def _w4a16_vmem_bytes(block_m: int, block_n: int, block_k: int,
+                      n_groups: int) -> int:
+    """Per-cell residency upper bound: double-buffered x (even + odd
+    halves, f32-sized), packed u8, scale/zero (full group row, lane-padded)
+    and output tiles, the f32 accumulator, and the four (bn, bk/2) f32
+    unpack/dequant temporaries the kernel materializes."""
+    bkh = block_k // 2
+    g_lanes = _round_up(n_groups, 128)
+    buffered = (4 * block_m * block_k + block_n * bkh
+                + 2 * 4 * block_n * g_lanes + 4 * block_m * block_n)
+    return 2 * buffered + 4 * block_m * block_n + 4 * 4 * block_n * bkh
+
+
+_W4A16_MAX_BLOCK_K = 1024
+
+
+def _w4a16_tiles(m: int, n: int, k: int, group_size: int):
+    """The dispatcher's tiling for an (m, k) x (n, k) int4 matmul.
+
+    Returns ``(block_m, block_n, block_k, m_pad, n_pad, k_pad)``. block_k
+    is all of k when k fits one step (a full-dim block is always legal),
+    else the largest multiple of ``lcm(256, group_size)`` up to
+    ``_W4A16_MAX_BLOCK_K`` that divides k — so ``block_k // 2`` is a
+    multiple of 128 lanes and a group never straddles tiles — with k
+    padded up to that step when nothing divides.
+    """
+    m_pad = _round_up(max(m, 1), 8)
+    block_m = 128 if m_pad >= 128 else m_pad
+    m_pad = _round_up(m_pad, block_m)
+    block_n = 128
+    n_pad = _round_up(n, block_n)
+    if k <= _W4A16_MAX_BLOCK_K:
+        return block_m, block_n, k, m_pad, n_pad, k
+    step = math.lcm(256, group_size)
+    k_pad = _round_up(k, step)
+    block_k = max(c for c in range(step, max(_W4A16_MAX_BLOCK_K, step) + 1,
+                                   step) if k_pad % c == 0)
+    return block_m, block_n, block_k, m_pad, n_pad, k_pad
 
 
 def w4a16_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
                  zeros: jax.Array, *, group_size: int = 128,
-                 impl: str | None = None) -> jax.Array:
-    """x: (..., k); packed: (n, k//2) u8; scales/zeros: (n, k//group_size)."""
+                 impl: str | None = None,
+                 interpret: bool | None = None) -> jax.Array:
+    """x: (..., k); packed: (n, k//2) u8; scales/zeros: (n, k//group_size).
+
+    ``interpret`` overrides the off-TPU interpret default, as in
+    :func:`gptq_block` (the compile tests lower the dispatcher's own tiles
+    for a described TPU with ``interpret=False``)."""
     if impl is None:
         impl = _W4A16_DEFAULT_IMPL
     if impl == "xla" or (impl == "auto" and not _on_tpu()):
@@ -169,10 +210,11 @@ def w4a16_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
     x2 = x.reshape(-1, x.shape[-1])
     m, k = x2.shape
     n = packed.shape[0]
-    block_m = 128 if m >= 128 else max(8, m)
-    block_n, block_k = 128, min(512, k)
-    if (impl == "auto" and _w4a16_vmem_bytes(block_m, block_n, block_k)
-            > _VMEM_BUDGET_BYTES):
+    block_m, block_n, block_k, m_pad, n_pad, k_pad = _w4a16_tiles(
+        m, n, k, group_size)
+    if (impl == "auto"
+            and _w4a16_vmem_bytes(block_m, block_n, block_k,
+                                  k_pad // group_size) > _VMEM_BUDGET_BYTES):
         _note_fallback("w4a16_matmul", "vmem-budget")
         y = ref.w4a16_matmul_ref(x2, packed, scales, zeros, group_size)
         return y.reshape(*lead, -1)
@@ -180,17 +222,20 @@ def w4a16_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
     # fused kernel would be traced — drives the serving engines' runtime
     # pallas→xla degradation path (docs/SERVING.md §Failure handling)
     faults.fire("kernels.pallas_dispatch")
-    m_pad, n_pad = _round_up(m, block_m), _round_up(n, block_n)
-    if m_pad != m:
-        x2 = jnp.pad(x2, ((0, m_pad - m), (0, 0)))
-    if n_pad != n:
-        packed = jnp.pad(packed, ((0, n_pad - n), (0, 0)))
-        scales = jnp.pad(scales, ((0, n_pad - n), (0, 0)),
+    if (m_pad, k_pad) != (m, k):
+        x2 = jnp.pad(x2, ((0, m_pad - m), (0, k_pad - k)))
+    if (n_pad, k_pad) != (n, k):
+        # padded columns meet zero activations and padded rows are sliced
+        # off, so the (s=1, z=0) filler never reaches a real output
+        g_pad = (k_pad - k) // group_size
+        packed = jnp.pad(packed, ((0, n_pad - n), (0, (k_pad - k) // 2)))
+        scales = jnp.pad(scales, ((0, n_pad - n), (0, g_pad)),
                          constant_values=1.0)
-        zeros = jnp.pad(zeros, ((0, n_pad - n), (0, 0)))
-    y = w4a16_matmul_pallas(x2, packed, scales, zeros, group_size=group_size,
-                            block_m=block_m, block_n=block_n, block_k=block_k,
-                            interpret=not _on_tpu())
+        zeros = jnp.pad(zeros, ((0, n_pad - n), (0, g_pad)))
+    y = w4a16_matmul_pallas(
+        x2, packed, scales, zeros, group_size=group_size, block_m=block_m,
+        block_n=block_n, block_k=block_k,
+        interpret=(not _on_tpu()) if interpret is None else interpret)
     return y[:m, :n].reshape(*lead, n)
 
 
@@ -219,25 +264,36 @@ def kv_attn_default_impl(impl: str):
         _KV_ATTN_DEFAULT_IMPL = prev
 
 
-def _kv_attn_vmem_bytes(block_s: int, r: int, hd: int, nb: int) -> int:
-    """Per-cell residency: q/acc/out tiles + two dequantized (bs, hd) K/V
-    tiles f32, the int8 code tiles, scale tiles, and the m/l scratch."""
-    return (4 * (3 * r * hd + 2 * block_s * hd + 2 * block_s * nb
-                 + 2 * r * 128 + r * block_s)
-            + 2 * block_s * hd)
+def _kv_attn_vmem_bytes(block_s: int, kv: int, r: int, hd: int,
+                        nb: int) -> int:
+    """Per-cell residency with Mosaic's tile padding: double-buffered
+    int8 K/V code tiles ((kv, hd) padded to (32, 128)), f32 scale tiles
+    ((kv, nb) padded to (8, 128)), q/out tiles and the kpos row; the
+    acc/m/l scratch; and one head's f32 temporaries (two dequantized
+    (bs, hd) tiles, scores and probabilities)."""
+    hd_l = _round_up(hd, 128)
+    codes = block_s * _round_up(kv, 32) * hd_l
+    scales = 4 * block_s * _round_up(kv, 8) * _round_up(nb, 128)
+    qo = 4 * kv * _round_up(r, 8) * hd_l
+    buffered = 2 * codes + 2 * scales + 2 * qo + 4 * 8 * block_s
+    scratch = 4 * kv * _round_up(r, 8) * (hd_l + 2 * 128)
+    temps = 4 * (2 * block_s * hd_l + 2 * _round_up(r, 8) * block_s)
+    return 2 * buffered + scratch + temps
 
 
 def int8_kv_attention(q: jax.Array, k_codes: jax.Array, k_scales: jax.Array,
                       v_codes: jax.Array, v_scales: jax.Array,
                       kpos: jax.Array, *, kv_block: int,
                       softcap: float = 0.0,
-                      impl: str | None = None) -> jax.Array:
+                      impl: str | None = None,
+                      interpret: bool | None = None) -> jax.Array:
     """One-token GQA decode against an int8 KV cache (kernels/kv_codec.py).
 
     q: (B, KV, R, hd) pre-scaled queries; k/v codes: (B, S, KV, hd) int8;
     k/v scales: (B, S, KV, hd//kv_block) f32; kpos: (B, S) int32 slot
     positions, -1 = invalid (causal/window validity is encoded by the
-    caller). Returns (B, KV, R, hd) in q.dtype.
+    caller). Returns (B, KV, R, hd) in q.dtype. ``interpret`` overrides
+    the off-TPU interpret default, as in :func:`w4a16_matmul`.
     """
     if impl is None:
         impl = _KV_ATTN_DEFAULT_IMPL
@@ -248,8 +304,9 @@ def int8_kv_attention(q: jax.Array, k_codes: jax.Array, k_scales: jax.Array,
     b, s, kv, hd = k_codes.shape
     r = q.shape[2]
     nb = hd // kv_block
+    # kpos rides along lanes, so a history tile is 128 slots or all of S
     block_s = 128 if s >= 128 else _round_up(s, 8)
-    if (impl == "auto" and _kv_attn_vmem_bytes(block_s, max(r, 8), hd, nb)
+    if (impl == "auto" and _kv_attn_vmem_bytes(block_s, kv, r, hd, nb)
             > _VMEM_BUDGET_BYTES):
         _note_fallback("int8_kv_attention", "vmem-budget")
         return ref.int8_kv_attention_ref(q, k_codes, k_scales, v_codes,
@@ -272,7 +329,9 @@ def int8_kv_attention(q: jax.Array, k_codes: jax.Array, k_scales: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
     y = int8_kv_attention_pallas(q, k_codes, k_scales, v_codes, v_scales,
                                  kpos, kv_block=kv_block, softcap=softcap,
-                                 block_s=block_s, interpret=not _on_tpu())
+                                 block_s=block_s,
+                                 interpret=((not _on_tpu()) if interpret
+                                            is None else interpret))
     return y[:, :, :r]
 
 
@@ -302,13 +361,24 @@ def quant_pack(w: jax.Array, scales: jax.Array, zeros: jax.Array, *,
 # GPTQ lazy-block sweep (stage-1 quantization hot path)
 # ---------------------------------------------------------------------------
 
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024     # conservative 16 MB minus headroom
+# Mosaic's default scoped-VMEM limit is 16 MiB per kernel (the v5e compiler
+# reports "limit 16.00M" when a kernel exceeds it); the budget keeps 4 MiB
+# of headroom under it. The per-kernel estimates below count every blocked
+# operand twice (the pipeline double-buffers them) and are upper bounds of
+# what the compiler allocates at opt-proxy's widths (tests/
+# test_tpu_compile.py compiles the dispatcher's own tiles).
+_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
-def _gptq_vmem_bytes(block_out: int, in_dim: int, blocksize: int) -> int:
-    """Per-cell residency: U (in²) + w-in/w-out tiles + the U row slab."""
-    return 4 * (in_dim * in_dim + 2 * block_out * in_dim
-                + blocksize * in_dim)
+def _gptq_vmem_bytes(block_out: int, in_dim: int, blocksize: int,
+                     group_size: int) -> int:
+    """Per-cell residency: U (in², double-buffered), the double-buffered
+    w-in/w-out row tiles and lane-padded scale/zero/err outputs, the U row
+    slab and the rank-bs update temporary."""
+    g_lanes = _round_up(in_dim // group_size, 128)
+    return 4 * (2 * in_dim * in_dim + 4 * block_out * in_dim
+                + blocksize * in_dim + block_out * in_dim
+                + 4 * block_out * g_lanes + 2 * block_out * 128)
 
 
 def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
@@ -354,7 +424,8 @@ def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
     use_pallas = impl == "pallas"
     if (impl == "auto" and _on_tpu()
             and (local or jax.device_count() == 1)):
-        if _gptq_vmem_bytes(bo, in_dim, blocksize) <= _VMEM_BUDGET_BYTES:
+        if (_gptq_vmem_bytes(bo, in_dim, blocksize, group_size)
+                <= _VMEM_BUDGET_BYTES):
             use_pallas = True
         else:
             _note_fallback("gptq_block", "vmem-budget")
@@ -413,7 +484,6 @@ def gptq_block_sharded(w: jax.Array, hinv_u: jax.Array, *, mesh,
     one dim only.  Under ``local=True`` dispatch, "auto" may lower the
     fused pallas kernel per shard on TPU.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if lane_axis is None and row_axis is None:
@@ -430,11 +500,11 @@ def gptq_block_sharded(w: jax.Array, hinv_u: jax.Array, *, mesh,
         return w_q, scales, zeros, err
 
     slab = P(lane_axis, row_axis, None)
-    return shard_map(
+    return jax.shard_map(
         local_sweep, mesh=mesh,
         in_specs=(slab, P(lane_axis, None, None)),
         out_specs=(slab, slab, slab, P(lane_axis)),
-        check_rep=False)(w, hinv_u)
+        check_vma=False)(w, hinv_u)
 
 
 # ---------------------------------------------------------------------------
@@ -444,11 +514,12 @@ def gptq_block_sharded(w: jax.Array, hinv_u: jax.Array, *, mesh,
 
 def _rpiq_vmem_bytes(block_out: int, in_dim: int, n: int,
                      block_size: int) -> int:
-    """Per-cell residency: five (block_out, in) tiles (W₀, working W, round
-    candidate, expanded scales/zeros) + the (n, in) instance slab + two
-    (n, block_out) output slabs + the (in, bs) inverse stack."""
-    return 4 * (5 * block_out * in_dim + n * in_dim
-                + 2 * n * block_out + block_size * in_dim)
+    """Per-cell residency, every blocked operand double-buffered: five
+    (block_out, in) tiles (W₀, working W, round candidate, expanded
+    scales/zeros) + the (n, in) instance slab + two (n, block_out) output
+    slabs + the (in, bs) inverse stack."""
+    return 2 * 4 * (5 * block_out * in_dim + n * in_dim
+                    + 2 * n * block_out + block_size * in_dim)
 
 
 _RPIQ_HBM_BUDGET_BYTES = 2 * 1024 ** 3   # per-dispatch candidate-stack cap
@@ -646,7 +717,6 @@ def rpiq_block_sharded(w_init: jax.Array, w_fp: jax.Array,
     the row axis like the stage-1 factor — DESIGN.md §2.6).  Either axis
     may be None; both None degrades to the single-device dispatcher.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.rpiq import rpiq_refine_batched
 
@@ -701,12 +771,12 @@ def rpiq_block_sharded(w_init: jax.Array, w_fp: jax.Array,
             interpret=interpret, local=True, loss_psum_axis=row_axis, **kw))
 
     # loss history / proj_loss / iters are identical across row shards
-    # after the psum fold — lane-sharded only (check_rep off, as in the
+    # after the psum fold — lane-sharded only (check_vma off, as in the
     # stage-1 twin)
     out_specs = (slab, slab, P(lane_axis, None), P(lane_axis),
                  P(lane_axis))
-    return shard_map(local_refine, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=out_specs, check_rep=False)(*args)
+    return jax.shard_map(local_refine, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -17,13 +17,20 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding rules here are
+    GSPMD-style (the compiler propagates shardings), which Explicit axes —
+    the default since JAX 0.9 — reject at the first resharding op."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,
@@ -37,18 +44,17 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,
     if expert > 1:
         if pod > 1:
             raise ValueError("expert axis does not compose with pod axis")
-        return jax.make_mesh((data, model, expert),
-                             ("data", "model", "expert"))
+        return _mesh((data, model, expert), ("data", "model", "expert"))
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_quant_mesh(spec: str = "off") -> Optional[Mesh]:
     """``quant.mesh`` knob → Mesh for sharded group execution.
 
     - "off" (default) / "" / "none" / "1x1" → None: single-device batched
-      execution, exactly the pre-mesh behavior;
+      execution;
     - "auto" → all local devices on the ``data`` axis (lane parallelism
       needs no Cout divisibility, so it degrades most gracefully);
     - "DxM" (e.g. "2x2", "8x1") → explicit (data, model) axis sizes over
@@ -57,16 +63,11 @@ def make_quant_mesh(spec: str = "off") -> Optional[Mesh]:
       groups made entirely of stacked expert slabs shard lanes over
       expert (×data), everything else ignores the axis.
 
-    Degrades to None (with a warning) when the spec is malformed or asks
-    for more devices than the process has — a quantize config carrying a
-    mesh knob stays runnable on a laptop, mirroring the per-group
-    divisibility fallback.
+    Raises ``ValueError`` when the spec is malformed or asks for more
+    devices than the process has: a run that asked for a mesh and got one
+    device would look like a pass of the sharded path without having run
+    it.
     """
-    def _fallback(why: str):
-        print(f"[mesh] quant.mesh={spec!r} {why} — falling back to "
-              f"single-device execution")
-        return None
-
     if not spec or spec in ("off", "none", "1", "1x1", "1x1x1"):
         return None
     if spec == "auto":
@@ -75,20 +76,19 @@ def make_quant_mesh(spec: str = "off") -> Optional[Mesh]:
             return None
         return make_host_mesh(data=n, model=1)
     parts = spec.lower().split("x")
-    if len(parts) not in (2, 3):
-        return _fallback("is not 'off', 'auto', 'DxM' or 'DxMxE'")
     try:
         sizes = [int(p) for p in parts]
     except ValueError:
-        return _fallback("is not 'off', 'auto', 'DxM' or 'DxMxE'")
-    if any(s < 1 for s in sizes):
-        return _fallback("has non-positive axis sizes")
+        sizes = []
+    if len(sizes) not in (2, 3) or any(s < 1 for s in sizes):
+        raise ValueError(f"quant.mesh={spec!r} is not 'off', 'auto', 'DxM' "
+                         "or 'DxMxE' with positive axis sizes")
     d, m = sizes[0], sizes[1]
     e = sizes[2] if len(sizes) == 3 else 1
     total = d * m * e
     if total <= 1:
         return None
-    if len(jax.devices()) < total:
-        return _fallback(f"needs {total} devices, have "
-                         f"{len(jax.devices())}")
+    if jax.device_count() < total:
+        raise ValueError(f"quant.mesh={spec!r} needs {total} devices, have "
+                         f"{jax.device_count()}")
     return make_host_mesh(data=d, model=m, expert=e)

@@ -37,6 +37,7 @@ from repro.core import faults
 from repro.core.pipeline import pack_for_serving, quantize_model
 from repro.data import MarkovLM, calibration_batches
 from repro.distributed.checkpoint import Checkpointer, save_artifact
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_quant_mesh
 from repro.models import transformer as T
 
@@ -52,6 +53,7 @@ def main(argv=None):
     cfg = get_config(args.arch, smoke=args.smoke)
     apply_overrides(cfg, parse_overrides(args.overrides))
     mc, qc = cfg.model, cfg.quant
+    setup_compile_cache()
     faults.install_from_config(cfg)
     if cfg.faults.arm:
         print(f"[quantize] fault plane armed: {cfg.faults.arm}")
@@ -117,6 +119,8 @@ def main(argv=None):
         print(f"[quantize] guardrail: {report.guardrail_stats}")
     if report.kernel_fallbacks:
         print(f"[quantize] kernel fallbacks: {report.kernel_fallbacks}")
+    if report.mesh_spread:
+        print(f"[quantize] mesh spread: {report.mesh_spread}")
     packed = pack_for_serving(cfg, params_q)
 
     os.makedirs(args.out, exist_ok=True)
@@ -130,6 +134,7 @@ def main(argv=None):
                   jax.device_get(packed), extra={"arch": tag})
     print(f"[quantize] wrote {args.out}/{tag}.params.pkl (+ integrity "
           "manifest)")
+    return report
 
 
 if __name__ == "__main__":

@@ -29,6 +29,7 @@ from repro.configs.registry import get_config
 from repro.core import faults
 from repro.data import MarkovLM
 from repro.distributed.checkpoint import load_artifact
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import generate
 from repro.serving.scheduler import ContinuousEngine
@@ -52,6 +53,7 @@ def main(argv=None):
     cfg = get_config(args.arch, smoke=args.smoke)
     apply_overrides(cfg, parse_overrides(args.overrides))
     mc = cfg.model
+    setup_compile_cache()
     faults.install_from_config(cfg)
     if cfg.faults.arm:
         print(f"[serve] fault plane armed: {cfg.faults.arm}")

@@ -11,11 +11,10 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.config import apply_overrides, parse_overrides
 from repro.configs.registry import get_config
 from repro.data import MarkovLM
+from repro.launch.mesh import make_host_mesh
 from repro.training.trainer import train
 
 
@@ -35,7 +34,7 @@ def main(argv=None):
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_host_mesh(data=d, model=m)
 
     data = MarkovLM(cfg.model.vocab_size, seed=cfg.train.seed)
     out = train(cfg, data, mesh=mesh)

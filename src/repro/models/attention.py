@@ -324,7 +324,8 @@ def _cache_key_positions(last: int, cache_len: int, window: int) -> jax.Array:
         off = (last - idx) % cache_len
         kpos = last - off
         lo = last - min(window, cache_len)
-        return jnp.where(kpos > lo, kpos, -1)
+        # kpos < 0: a slot the ring has not reached yet (last < cache_len-1)
+        return jnp.where((kpos > lo) & (kpos >= 0), kpos, -1)
     return jnp.where(idx <= last, idx, -1)
 
 

@@ -244,20 +244,19 @@ def moe_ffn(cfg: ModelConfig, p: Dict, x: jax.Array,
     if (rules is not None and rules.dp_axes
             and getattr(rules, "ep_local_dispatch", True)
             and x.shape[0] % rules.dp_size() == 0):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         dp = tuple(rules.dp_axes)
-        auto = frozenset(rules.mesh.axis_names) - frozenset(dp)
 
         def local(xl):
             out = _moe_ffn_body(cfg, p, xl, name)
             return (out.y, jax.lax.pmean(out.aux_loss, dp),
                     jax.lax.pmean(out.expert_load, dp))
 
-        y, aux, load = shard_map(
+        # manual over the DP axes only; the rest of the mesh stays auto
+        y, aux, load = jax.shard_map(
             local, mesh=rules.mesh,
             in_specs=(P(dp),), out_specs=(P(dp), P(), P()),
-            check_rep=False, auto=auto)(x)
+            axis_names=frozenset(dp), check_vma=False)(x)
         return MoEOutput(y, aux, load)
     return _moe_ffn_body(cfg, p, x, name)
 

@@ -62,15 +62,14 @@ def engine_stats() -> Dict[str, int]:
 def _kernel_fault(e: Exception) -> bool:
     """Is this exception a kernel-path failure worth degrading over?
 
-    Injected faults carry a ``.site`` — only ``kernels.pallas_dispatch``
-    counts (other sites must propagate to their own handlers). A real
-    exception from inside a pallas dispatch has no site attribute and is
-    treated as a kernel fault by the caller that just ran one.
+    Only an injected fault at the ``kernels.pallas_dispatch`` site is.
+    Injected faults at other sites propagate to their own handlers, and a
+    real exception (a Mosaic lowering or compile error, a shape bug)
+    propagates to the caller: degrading over it would serve every request
+    through the XLA reference while the kernel that should run on the
+    device is broken, with nothing but a counter to show for it.
     """
-    site = getattr(e, "site", None)
-    if site is not None:
-        return site == "kernels.pallas_dispatch"
-    return True
+    return getattr(e, "site", None) == "kernels.pallas_dispatch"
 
 
 def decode_step_guarded(cfg: Config, params: Any, token: jax.Array,
@@ -171,9 +170,10 @@ def generate(cfg: Config, params: Any, batch: Dict[str, jax.Array], *,
              seed: int = 0) -> GenResult:
     """Greedy/temperature generation. Static shapes; jit-compiled loop.
 
-    A kernel fault on a pallas path (w4a16 matmul or the fused int8-KV
-    attention) degrades this call to the xla reference backends and retries
-    once — counted in ``engine_stats()``, never silent.
+    An injected kernel fault on a pallas path (w4a16 matmul or the fused
+    int8-KV attention) degrades this call to the xla reference backends and
+    retries once — counted in ``engine_stats()``, never silent. Any other
+    error propagates.
     """
     impl = cfg.serve.w4a16_impl
     kv_impl = cfg.serve.kv_impl

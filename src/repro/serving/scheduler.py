@@ -177,9 +177,10 @@ class ContinuousEngine:
 
     def _guarded(self, name: str, *args):
         """Run one jitted piece under the current kernel backends (w4a16
-        matmul + int8-KV attention); on a kernel fault, degrade pallas→xla
-        (rebuild jits, count, warn) and retry the same call once.
-        Already-xla faults and non-kernel faults propagate."""
+        matmul + int8-KV attention); on an injected kernel fault, degrade
+        pallas→xla (rebuild jits, count, warn) and retry the same call once.
+        Already-xla faults, other sites' faults and real errors (a Mosaic
+        lowering failure included) propagate."""
         with kops.w4a16_default_impl(self._impl), \
                 kops.kv_attn_default_impl(self._kv_impl), \
                 kops.fallback_scope(self._kernel_fallbacks):

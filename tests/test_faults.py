@@ -415,6 +415,35 @@ class TestServingHardening:
         np.testing.assert_array_equal(np.asarray(ref.tokens),
                                       np.asarray(res.tokens))
 
+    @pytest.mark.parametrize("entry", ["continuous_step", "generate"])
+    def test_real_kernel_error_reaches_caller(self, entry, monkeypatch):
+        """A lowering error raised inside the pallas dispatch (no fault
+        site) propagates out of ContinuousEngine.step() / generate() and
+        degrades nothing."""
+        cfg_x, packed = _serve_setup(packed=True, w4a16_impl="xla")
+        cfg_p = dataclasses.replace(cfg_x, serve=dataclasses.replace(
+            cfg_x.serve, w4a16_impl="pallas"))
+
+        def broken(*a, **k):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setattr(kops, "w4a16_matmul_pallas", broken)
+        before = E.engine_stats()["kernel_degradations"]
+        if entry == "generate":
+            batch = MarkovLM(cfg_p.model.vocab_size, seed=0).batch(2, 8)
+            with pytest.raises(RuntimeError, match="Mosaic"):
+                E.generate(cfg_p, packed, batch, max_new_tokens=2,
+                           temperature=0.0)
+            assert E.engine_stats()["kernel_degradations"] == before
+        else:
+            eng = ContinuousEngine(cfg_p, packed, max_len=64)
+            _submit_n(eng, mnt=3)
+            with pytest.raises(RuntimeError, match="Mosaic"):
+                eng.step()
+            stats = eng.engine_stats()
+            assert stats["kernel_degradations"] == 0
+            assert stats["w4a16_impl"] == "pallas"
+
     def test_non_kernel_fault_is_not_swallowed(self):
         """A request-level fault inside a guarded call must propagate to its
         own handler, not trigger a kernel degradation."""
@@ -422,7 +451,9 @@ class TestServingHardening:
                                                      "kill", 1))
         assert E._kernel_fault(faults.FaultError("kernels.pallas_dispatch",
                                                  "kill", 1))
-        assert E._kernel_fault(RuntimeError("mosaic lowering failed"))
+        # a real error carries no site: it must reach the caller instead of
+        # quietly moving the engine onto the XLA reference
+        assert not E._kernel_fault(RuntimeError("mosaic lowering failed"))
 
 
 # ---------------------------------------------------------------------------

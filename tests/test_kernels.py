@@ -98,6 +98,21 @@ class TestW4A16Kernel:
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-3)
 
+    @pytest.mark.parametrize("k", [1152, 1408])
+    def test_ops_pallas_pads_k(self, k):
+        """k above one K step and off the 256-column tile: the dispatcher
+        pads k (and m, n) with zero activations and (s=1, z=0) groups, and
+        the padding never reaches a real output."""
+        from repro.kernels import ops
+        x = _rand((5, k), 8)
+        w = _rand((100, k), 9) * 0.3
+        qt = pack_quantized(w, 4, 128)
+        y = ops.w4a16_matmul(x, qt.packed, qt.scales, qt.zeros,
+                             group_size=128, impl="pallas")
+        y_ref = ref.w4a16_matmul_ref(x, qt.packed, qt.scales, qt.zeros, 128)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   rtol=1e-4, atol=1e-3)
+
     @pytest.mark.parametrize("lead", [(3, 1), (2, 5), (4,)])
     @pytest.mark.parametrize("impl", ["xla", "pallas"])
     def test_decode_shapes(self, lead, impl):

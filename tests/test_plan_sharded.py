@@ -383,13 +383,16 @@ def test_make_quant_mesh_off_variants():
     from repro.launch.mesh import make_quant_mesh
     for spec in ("off", "", "none", "1x1", "1", "1x1x1"):
         assert make_quant_mesh(spec) is None
-    # malformed specs degrade gracefully instead of raising
+    # malformed specs raise: a run that asked for a mesh must not quietly
+    # run single-device
     for spec in ("x4", "axb", "-2x-2", "0x4", "2x2x2x2"):
-        assert make_quant_mesh(spec) is None
-    # "DxMxE" is valid grammar; without enough devices it degrades to
-    # single-device like any oversized spec
-    assert make_quant_mesh("2x2x2") is None or \
-        jax.device_count() >= 8
+        with pytest.raises(ValueError, match="is not"):
+            make_quant_mesh(spec)
+    # "DxMxE" is valid grammar; without enough devices it raises like any
+    # oversized spec
+    if jax.device_count() < 8:
+        with pytest.raises(ValueError, match="needs 8 devices"):
+            make_quant_mesh("2x2x2")
     # uppercase separator is accepted
     assert make_quant_mesh("1X1") is None
 
@@ -402,5 +405,7 @@ def test_make_quant_mesh_shapes_and_fallback():
     assert tuple(mesh.devices.shape) == (2, 2)
     auto = make_quant_mesh("auto")
     assert dict(zip(auto.axis_names, auto.devices.shape))["model"] == 1
-    # more devices than the host has → graceful single-device fallback
-    assert make_quant_mesh("64x64") is None
+    # more devices than the host has → an error, never a silent
+    # single-device run
+    with pytest.raises(ValueError, match="needs 4096 devices"):
+        make_quant_mesh("64x64")
