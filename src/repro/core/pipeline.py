@@ -65,6 +65,7 @@ from repro.config import Config
 from repro.core import faults
 from repro.core import hessian as hess
 from repro.core import plan as qplan
+from repro.core import spans
 from repro.core import stream as qstream
 from repro.core.plan import (LinearRecord, MemberResult,  # noqa: F401
                              PlanMember, QuantReport)
@@ -151,17 +152,20 @@ def _layer_forward_jit(fwd_cache: Dict, fwd_key: Tuple, apply_fn,
     key = (fwd_key, key_bi, collect, _tree_signature(params), h.shape,
            str(h.dtype))
     fn = fwd_cache.get(key)
-    if fn is None:
-        def fwd(p, hh, _bi=bi):
-            if not collect:
-                return apply_fn(p, hh, _bi), {}
-            tap = Tap(collect_tracers=True)
-            with tap:
-                out = apply_fn(p, hh, _bi)
-            return out, {k: list(v) for k, v in tap.records.items()}
-        fn = jax.jit(fwd)
-        fwd_cache[key] = fn
-    return fn(params, h)
+    if fn is not None:
+        return fn(params, h)
+
+    def fwd(p, hh, _bi=bi):
+        if not collect:
+            return apply_fn(p, hh, _bi), {}
+        tap = Tap(collect_tracers=True)
+        with tap:
+            out = apply_fn(p, hh, _bi)
+        return out, {k: list(v) for k, v in tap.records.items()}
+    # the first call traces, lowers and compiles (or loads) the forward
+    with spans.span(spans.FWD_BUILD):
+        fn = fwd_cache[key] = jax.jit(fwd)
+        return fn(params, h)
 
 
 def _resolve(tree: Dict, dotted: str):
@@ -373,6 +377,7 @@ class CaptureResult:
     spec_routes: Optional[List] = None
 
 
+@spans.spanned(spans.CAPTURE)
 def capture_layer(cfg: Config, step: LayerStep, hs: List[jax.Array],
                   fwd_cache: Optional[Dict] = None,
                   speculative: bool = False,
@@ -444,6 +449,7 @@ def capture_layer(cfg: Config, step: LayerStep, hs: List[jax.Array],
                          spec_routes)
 
 
+@spans.spanned(spans.PLAN)
 def plan_layer(cfg: Config, step: LayerStep, cap: CaptureResult,
                hs: List[jax.Array], report: Optional[QuantReport] = None,
                stats: Optional[Dict] = None,
@@ -714,23 +720,26 @@ def quantize_model(cfg: Config, params: Dict,
     global _LAST_FWD_STATS
     t_start = time.perf_counter()
     report = QuantReport()
-    if mesh is _MESH_FROM_CONFIG:
-        from repro.launch.mesh import make_quant_mesh
-        mesh = make_quant_mesh(cfg.quant.mesh)
+    with spans.recording(report.spans), spans.span(spans.JOB):
+        if mesh is _MESH_FROM_CONFIG:
+            from repro.launch.mesh import make_quant_mesh
+            mesh = make_quant_mesh(cfg.quant.mesh)
 
-    fwd_cache = ForwardCache()   # per-run compiled-forward cache (jit_capture)
-    build = (_walker_encdec if cfg.model.is_encoder_decoder
-             else _walker_decoder_only)
-    walker = build(cfg, params, calib)
-    fb0 = kops.fallback_stats()
-    try:
-        out = qstream.run_walker(cfg, walker, report, fwd_cache=fwd_cache,
-                                 mesh=mesh, verbose=verbose)
-    finally:
-        # only the counters outlive the run — keeping the cache itself
-        # alive would pin every compiled forward and its baked closure
-        # constants (positions, enc_out) past the model they belong to
-        _LAST_FWD_STATS = fwd_cache.stats()
+        fwd_cache = ForwardCache()   # per-run compiled forwards (jit_capture)
+        build = (_walker_encdec if cfg.model.is_encoder_decoder
+                 else _walker_decoder_only)
+        with spans.span(spans.WALKER):
+            walker = build(cfg, params, calib)
+        fb0 = kops.fallback_stats()
+        try:
+            out = qstream.run_walker(cfg, walker, report,
+                                     fwd_cache=fwd_cache, mesh=mesh,
+                                     verbose=verbose)
+        finally:
+            # only the counters outlive the run — keeping the cache itself
+            # alive would pin every compiled forward and its baked closure
+            # constants (positions, enc_out) past the model they belong to
+            _LAST_FWD_STATS = fwd_cache.stats()
     # auto→xla kernel downgrades observed during THIS run (delta against
     # the process-wide counters): surfaced so a budget-driven fallback is
     # visible in the report instead of silently changing the backend

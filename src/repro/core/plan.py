@@ -46,7 +46,6 @@ mesh + resolved sharding.  ``quant.mesh`` plumbs this from configs
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -57,6 +56,7 @@ import numpy as np
 from repro.config import QuantConfig
 from repro.core import faults
 from repro.core import hessian as hess
+from repro.core import spans
 from repro.core.gptq import (GPTQResult, gptq_quantize,
                              gptq_quantize_batched, rtn_quantize,
                              rtn_quantize_batched)
@@ -80,7 +80,6 @@ class LinearRecord:
     iters: int
     mode: str                        # "rpiq" | "gptq" | "rtn-fallback" |
     #                                  "rtn-guardrail" | "skipped"
-    seconds: float
 
 
 @dataclasses.dataclass
@@ -89,7 +88,6 @@ class QuantReport:
     seconds_total: float = 0.0
     seconds_stage1: float = 0.0
     seconds_stage2: float = 0.0
-    peak_resident_bytes: int = 0     # analytic single-instance residency
     # stream-scheduler telemetry (core/stream.py): wall seconds per
     # layer-step (the overlap schedule's only sync point is the step's
     # report boundary, so this is its per-layer measurement; under serial
@@ -114,6 +112,10 @@ class QuantReport:
     # each stage's output held on the mesh before the gather
     # ({"stage1_shards=4": n, ...}) — shows the work really spread
     mesh_spread: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # host spans of a run traced under jax.profiler, (name, start_ns,
+    # end_ns) on time.perf_counter_ns (core/spans.py); empty untraced
+    spans: List[Tuple[str, int, int]] = dataclasses.field(
+        default_factory=list)
 
     def summary(self) -> str:
         n = len(self.linears)
@@ -399,11 +401,13 @@ def _make_stage2(qc: QuantConfig, impl: str,
               t_max=qc.rpiq_iters, early_stop=qc.rpiq_early_stop,
               symmetric=qc.symmetric,
               exact_gram=not qc.rpiq_use_global_hessian)
-    if gshard is None:
-        return jax.jit(functools.partial(rpiq_refine_batched, impl=impl,
-                                         **kw))
-
-    def fn(w_init, w_fp, x, hd, scales, zeros, h_count=None, x_count=None):
+    # a named function, so the device trace names the program jit_stage2
+    def stage2(w_init, w_fp, x, hd, scales, zeros, h_count=None,
+               x_count=None):
+        if gshard is None:
+            return rpiq_refine_batched(w_init, w_fp, x, hd, scales, zeros,
+                                       h_count=h_count, x_count=x_count,
+                                       impl=impl, **kw)
         # the stage-2 shard_map twin: lanes shard like stage 1; rows shard
         # only when the per-shard dispatch resolves to the fused kernel
         # (the closed-loop bookkeeping is global over rows — see
@@ -413,7 +417,7 @@ def _make_stage2(qc: QuantConfig, impl: str,
             x_count=x_count, mesh=gshard.mesh, lane_axis=gshard.lane_axis,
             row_axis=gshard.row_axis, impl=impl, **kw))
 
-    return jax.jit(fn)
+    return jax.jit(stage2)
 
 
 def _execute_group_batched(qc: QuantConfig, group: QuantGroup,
@@ -449,98 +453,128 @@ def _execute_group_batched(qc: QuantConfig, group: QuantGroup,
     """
     ms = group.members
     t0 = time.perf_counter()
-    w = jnp.concatenate([_as3d(jnp.asarray(m.w_oi, jnp.float32))
-                         for m in ms])
-    hs_lanes = [_lane_hessians(m) for m in ms]
-    st = hess.HessianState(jnp.concatenate([h.H for h in hs_lanes]),
-                           jnp.concatenate([h.count for h in hs_lanes]))
-    starved = np.concatenate([m.starved_mask() for m in ms])
-    with_rtn = bool(starved.any())
-    fspec = faults.poll("hessian.cholesky")
-    if fspec is not None:
-        st = hess.HessianState(
-            hess.corrupt_stacked(st.H, fspec.mode, qc.percdamp), st.count)
-    shard_key = None if gshard is None else gshard.cache_key()
-    if gshard is not None:
-        w = jax.device_put(w, gshard.sharding("w"))
-        st = hess.shard_stacked(st, gshard)
-    stage1 = _cached_executor(
-        ("stage1", group.key, qc.gptq_impl, with_rtn, shard_key),
-        lambda: _make_stage1(qc, qc.gptq_impl, with_rtn, gshard))
-    faults.fire("plan.stage1_executor")
-    lanes_total = int(w.shape[0])
-    damp = jnp.full((lanes_total,), qc.percdamp, jnp.float32)
-    hd, res1, rtn = stage1(w, st.H, damp)
-    guarded = np.zeros(lanes_total, bool)
-    if qc.guardrail:
-        bad0 = bad = ~_finite_lanes(res1)
-        rung = 0
-        while bad.any() and rung < qc.guardrail_retries:
-            # guardrail ladder rung: escalate damping only on lanes whose
-            # stage-1 output went non-finite (non-PSD / NaN Hessian).
-            # Every stage-1 op is lane-independent, so untouched lanes
-            # reproduce bitwise and the retry reuses the cached executor.
-            rung += 1
-            _guardrail_stats(report)["damp_retries"] += 1
-            damp = jnp.where(jnp.asarray(bad),
-                             damp * jnp.float32(qc.guardrail_damp_factor),
-                             damp)
-            hd, res1, rtn = stage1(w, st.H, damp)
-            bad = ~_finite_lanes(res1)
-        if bad0.any():
-            gs = _guardrail_stats(report)
-            gs["lanes_flagged"] += int(bad0.sum())
-            gs["lanes_damp_recovered"] += int((bad0 & ~bad).sum())
-            gs["lanes_rtn_forced"] += int(bad.sum())
-        if bad.any():
-            # ladder exhausted → per-group RTN rung. Stage 2 still runs
-            # these lanes under vmap, so feed it sanitized inputs (RTN
-            # weights on the RTN grid, identity curvature): a NaN Γ never
-            # satisfies the early-stop predicate and would pin the whole
-            # group's while_loop at t_max. The mask below discards their
-            # stage-2 output anyway.
-            guarded = np.asarray(bad)
-            if rtn is None:
-                rtn = rtn_quantize_batched(w, bits=qc.bits,
-                                           group_size=qc.group_size,
-                                           symmetric=qc.symmetric)
-            gj = jnp.asarray(guarded)
-            sel3 = gj[:, None, None]
-            hd = jnp.where(sel3, jnp.eye(hd.shape[-1], dtype=hd.dtype), hd)
-            res1 = GPTQResult(jnp.where(sel3, rtn.w_q, res1.w_q),
-                              jnp.where(sel3, rtn.scales, res1.scales),
-                              jnp.where(sel3, rtn.zeros, res1.zeros),
-                              jnp.where(gj, 0.0, res1.err))
-    if sync:
-        jax.block_until_ready(res1.w_q)
+    with spans.span(spans.STAGE1_INPUTS):
+        w = jnp.concatenate([_as3d(jnp.asarray(m.w_oi, jnp.float32))
+                             for m in ms])
+        hs_lanes = [_lane_hessians(m) for m in ms]
+        st = hess.HessianState(jnp.concatenate([h.H for h in hs_lanes]),
+                               jnp.concatenate([h.count for h in hs_lanes]))
+        starved = np.concatenate([m.starved_mask() for m in ms])
+        with_rtn = bool(starved.any())
+        fspec = faults.poll("hessian.cholesky")
+        if fspec is not None:
+            st = hess.HessianState(
+                hess.corrupt_stacked(st.H, fspec.mode, qc.percdamp),
+                st.count)
+        shard_key = None if gshard is None else gshard.cache_key()
+        if gshard is not None:
+            w = jax.device_put(w, gshard.sharding("w"))
+            st = hess.shard_stacked(st, gshard)
+        stage1 = _cached_executor(
+            ("stage1", group.key, qc.gptq_impl, with_rtn, shard_key),
+            lambda: _make_stage1(qc, qc.gptq_impl, with_rtn, gshard))
+        faults.fire("plan.stage1_executor")
+        lanes_total = int(w.shape[0])
+        damp = jnp.full((lanes_total,), qc.percdamp, jnp.float32)
+    with spans.span(spans.STAGE1):
+        hd, res1, rtn, guarded = _stage1_guarded(qc, stage1, w, st.H, damp,
+                                                 report)
+        if sync:
+            jax.block_until_ready(res1.w_q)
     t1 = time.perf_counter()
     report.seconds_stage1 += t1 - t0
 
     do_rpiq = rpiq_enabled and qc.rpiq_iters > 0
     res2 = None
     if do_rpiq:
-        x = jnp.concatenate([_as3d(jnp.asarray(m.x_last, jnp.float32))
-                             for m in ms])
-        xc = jnp.concatenate([_lane_x_counts(m) for m in ms])
-        if gshard is not None:
-            # commit the instance batch lane-local so the stage-2 shard_map
-            # twin (rpiq_block_sharded) keeps each lane's refinement where
-            # its rows run without a gather at dispatch
-            x = jax.device_put(x, gshard.sharding("x"))
-            xc = jax.device_put(xc, gshard.sharding("lane"))
-        stage2 = _cached_executor(
-            ("stage2", group.key, qc.rpiq_alpha, qc.rpiq_iters,
-             qc.rpiq_early_stop, qc.rpiq_use_global_hessian, qc.rpiq_impl,
-             shard_key),
-            lambda: _make_stage2(qc, qc.rpiq_impl, gshard))
-        faults.fire("plan.stage2_executor")
-        res2 = stage2(res1.w_q, w, x, hd, res1.scales, res1.zeros,
-                      h_count=st.count, x_count=xc)
-        if sync:
-            jax.block_until_ready(res2.w_q)
+        with spans.span(spans.STAGE2_INPUTS):
+            x = jnp.concatenate([_as3d(jnp.asarray(m.x_last, jnp.float32))
+                                 for m in ms])
+            xc = jnp.concatenate([_lane_x_counts(m) for m in ms])
+            if gshard is not None:
+                # commit the instance batch lane-local so the stage-2
+                # shard_map twin (rpiq_block_sharded) keeps each lane's
+                # refinement where its rows run without a gather at dispatch
+                x = jax.device_put(x, gshard.sharding("x"))
+                xc = jax.device_put(xc, gshard.sharding("lane"))
+            stage2 = _cached_executor(
+                ("stage2", group.key, qc.rpiq_alpha, qc.rpiq_iters,
+                 qc.rpiq_early_stop, qc.rpiq_use_global_hessian,
+                 qc.rpiq_impl, shard_key),
+                lambda: _make_stage2(qc, qc.rpiq_impl, gshard))
+            faults.fire("plan.stage2_executor")
+        with spans.span(spans.STAGE2):
+            res2 = stage2(res1.w_q, w, x, hd, res1.scales, res1.zeros,
+                          h_count=st.count, x_count=xc)
+            if sync:
+                jax.block_until_ready(res2.w_q)
         t2 = time.perf_counter()
         report.seconds_stage2 += t2 - t1
 
+    with spans.span(spans.RESULTS):
+        return _group_results(ms, report, res1, res2, rtn, starved, guarded,
+                              gshard, deferred)
+
+
+def _stage1_guarded(qc: QuantConfig, stage1: Callable, w: jax.Array,
+                    H: jax.Array, damp: jax.Array, report: QuantReport):
+    """Stage 1 under the guardrail ladder; returns (hd, res1, rtn,
+    guarded), ``guarded`` the (B,) host mask of lanes forced to RTN."""
+    hd, res1, rtn = stage1(w, H, damp)
+    guarded = np.zeros(int(w.shape[0]), bool)
+    if not qc.guardrail:
+        return hd, res1, rtn, guarded
+    bad0 = bad = ~_finite_lanes(res1)
+    rung = 0
+    while bad.any() and rung < qc.guardrail_retries:
+        # guardrail ladder rung: escalate damping only on lanes whose
+        # stage-1 output went non-finite (non-PSD / NaN Hessian).
+        # Every stage-1 op is lane-independent, so untouched lanes
+        # reproduce bitwise and the retry reuses the cached executor.
+        rung += 1
+        _guardrail_stats(report)["damp_retries"] += 1
+        damp = jnp.where(jnp.asarray(bad),
+                         damp * jnp.float32(qc.guardrail_damp_factor),
+                         damp)
+        hd, res1, rtn = stage1(w, H, damp)
+        bad = ~_finite_lanes(res1)
+    if bad0.any():
+        gs = _guardrail_stats(report)
+        gs["lanes_flagged"] += int(bad0.sum())
+        gs["lanes_damp_recovered"] += int((bad0 & ~bad).sum())
+        gs["lanes_rtn_forced"] += int(bad.sum())
+    if bad.any():
+        # ladder exhausted → per-group RTN rung. Stage 2 still runs
+        # these lanes under vmap, so feed it sanitized inputs (RTN
+        # weights on the RTN grid, identity curvature): a NaN Γ never
+        # satisfies the early-stop predicate and would pin the whole
+        # group's while_loop at t_max. The mask in _group_results
+        # discards their stage-2 output anyway.
+        guarded = np.asarray(bad)
+        if rtn is None:
+            rtn = rtn_quantize_batched(w, bits=qc.bits,
+                                       group_size=qc.group_size,
+                                       symmetric=qc.symmetric)
+        gj = jnp.asarray(guarded)
+        sel3 = gj[:, None, None]
+        hd = jnp.where(sel3, jnp.eye(hd.shape[-1], dtype=hd.dtype), hd)
+        res1 = GPTQResult(jnp.where(sel3, rtn.w_q, res1.w_q),
+                          jnp.where(sel3, rtn.scales, res1.scales),
+                          jnp.where(sel3, rtn.zeros, res1.zeros),
+                          jnp.where(gj, 0.0, res1.err))
+    return hd, res1, rtn, guarded
+
+
+def _group_results(ms: List[PlanMember], report: QuantReport,
+                   res1: GPTQResult, res2: Optional[RPIQResult], rtn,
+                   starved: np.ndarray, guarded: np.ndarray,
+                   gshard: Optional[QuantGroupSharding],
+                   deferred: Optional[List[Callable[[], None]]]
+                   ) -> List[MemberResult]:
+    """A group's outcome: the RTN mask, the gather off the mesh, the
+    report records (now, or queued into ``deferred``) and the per-member
+    slices of the stacked results."""
+    do_rpiq = res2 is not None
     # starved-expert + guardrail-forced mask: select the RTN lane
     # (weights AND grid)
     w_final = res2.w_q if do_rpiq else res1.w_q
@@ -567,16 +601,14 @@ def _execute_group_batched(qc: QuantConfig, group: QuantGroup,
         w_final, scales, zeros = (jax.device_put(a, dev0)
                                   for a in (w_final, scales, zeros))
 
-    seconds = (time.perf_counter() - t0) / max(1, int((~starved).sum()))
-
     def _record():
         # np.asarray synchronizes on the executor outputs — under the
         # overlap schedule this runs deferred, at the step's report
         # boundary, so the dispatch queue has already been refilled.
         err1 = np.asarray(res1.err)
-        hist = np.asarray(res2.loss_history) if res2 is not None else None
-        ploss = np.asarray(res2.proj_loss) if res2 is not None else None
-        iters = np.asarray(res2.iters_run) if res2 is not None else None
+        hist = np.asarray(res2.loss_history) if do_rpiq else None
+        ploss = np.asarray(res2.proj_loss) if do_rpiq else None
+        iters = np.asarray(res2.iters_run) if do_rpiq else None
         off = 0
         for m in ms:
             shape = m.wshape
@@ -584,18 +616,17 @@ def _execute_group_batched(qc: QuantConfig, group: QuantGroup,
                 i = off + li
                 if starved[i]:
                     report.linears.append(LinearRecord(
-                        lname, shape, 0.0, [], 0.0, 0, "rtn-fallback", 0.0))
+                        lname, shape, 0.0, [], 0.0, 0, "rtn-fallback"))
                 elif guarded[i]:
                     report.linears.append(LinearRecord(
-                        lname, shape, 0.0, [], 0.0, 0, "rtn-guardrail", 0.0))
+                        lname, shape, 0.0, [], 0.0, 0, "rtn-guardrail"))
                 elif do_rpiq:
                     report.linears.append(LinearRecord(
                         lname, shape, float(err1[i]), _gamma_list(hist[i]),
-                        float(ploss[i]), int(iters[i]), "rpiq", seconds))
+                        float(ploss[i]), int(iters[i]), "rpiq"))
                 else:
                     report.linears.append(LinearRecord(
-                        lname, shape, float(err1[i]), [], 0.0, 0, "gptq",
-                        seconds))
+                        lname, shape, float(err1[i]), [], 0.0, 0, "gptq"))
             off += m.lanes
 
     if deferred is None:
@@ -649,38 +680,42 @@ def _execute_member_singleton(qc: QuantConfig, m: PlanMember,
         res = rtn_quantize(jnp.asarray(m.w_oi, jnp.float32), bits=qc.bits,
                            group_size=qc.group_size, symmetric=qc.symmetric)
         report.linears.append(LinearRecord(
-            m.name, shape, 0.0, [], 0.0, 0, "rtn-fallback", 0.0))
+            m.name, shape, 0.0, [], 0.0, 0, "rtn-fallback"))
         return MemberResult(m.name, res.w_q, (res.scales, res.zeros))
     t0 = time.perf_counter()
-    w_oi = jnp.asarray(m.w_oi, jnp.float32)
-    hd = hess.damped(m.hessian, qc.percdamp)
-    u = hess.cholesky_inverse_upper(hd)
-    res1 = gptq_quantize(w_oi, u, bits=qc.bits, group_size=qc.group_size,
-                         blocksize=qc.blocksize, symmetric=qc.symmetric,
-                         impl=qc.gptq_impl)
-    jax.block_until_ready(res1.w_q)
+    with spans.span(spans.STAGE1):
+        w_oi = jnp.asarray(m.w_oi, jnp.float32)
+        hd = hess.damped(m.hessian, qc.percdamp)
+        u = hess.cholesky_inverse_upper(hd)
+        res1 = gptq_quantize(w_oi, u, bits=qc.bits, group_size=qc.group_size,
+                             blocksize=qc.blocksize, symmetric=qc.symmetric,
+                             impl=qc.gptq_impl)
+        jax.block_until_ready(res1.w_q)
     t1 = time.perf_counter()
     report.seconds_stage1 += t1 - t0
     grid = (res1.scales, res1.zeros)
     if not rpiq_enabled or qc.rpiq_iters <= 0:
         report.linears.append(LinearRecord(
-            m.name, shape, float(res1.err), [], 0.0, 0, "gptq", t1 - t0))
+            m.name, shape, float(res1.err), [], 0.0, 0, "gptq"))
         return MemberResult(m.name, res1.w_q, grid)
-    res2 = rpiq_refine(res1.w_q, w_oi, jnp.asarray(m.x_last, jnp.float32),
-                       hd, res1.scales, res1.zeros,
-                       h_count=m.hessian.count, x_count=m.x_count,
-                       bits=qc.bits, group_size=qc.group_size,
-                       block_size=qc.blocksize, alpha=qc.rpiq_alpha,
-                       t_max=qc.rpiq_iters, early_stop=qc.rpiq_early_stop,
-                       exact_gram=not qc.rpiq_use_global_hessian,
-                       symmetric=qc.symmetric, impl=qc.rpiq_impl)
-    jax.block_until_ready(res2.w_q)
+    with spans.span(spans.STAGE2):
+        res2 = rpiq_refine(res1.w_q, w_oi,
+                           jnp.asarray(m.x_last, jnp.float32), hd,
+                           res1.scales, res1.zeros,
+                           h_count=m.hessian.count, x_count=m.x_count,
+                           bits=qc.bits, group_size=qc.group_size,
+                           block_size=qc.blocksize, alpha=qc.rpiq_alpha,
+                           t_max=qc.rpiq_iters,
+                           early_stop=qc.rpiq_early_stop,
+                           exact_gram=not qc.rpiq_use_global_hessian,
+                           symmetric=qc.symmetric, impl=qc.rpiq_impl)
+        jax.block_until_ready(res2.w_q)
     t2 = time.perf_counter()
     report.seconds_stage2 += t2 - t1
     report.linears.append(LinearRecord(
         m.name, shape, float(res1.err), _gamma_list(np.asarray(
             res2.loss_history)), float(res2.proj_loss),
-        int(res2.iters_run), "rpiq", t2 - t0))
+        int(res2.iters_run), "rpiq"))
     return MemberResult(m.name, res2.w_q, grid)
 
 
@@ -715,18 +750,18 @@ def _execute_fallback(qc: QuantConfig, m: PlanMember, report: QuantReport,
                                bits=qc.bits, group_size=gsz,
                                symmetric=qc.symmetric)
             recs.append(LinearRecord(
-                m.name, shape, 0.0, [], 0.0, 0, "rtn-fallback", 0.0))
+                m.name, shape, 0.0, [], 0.0, 0, "rtn-fallback"))
             _emit()
             return MemberResult(m.name, res.w_q,
                                 (res.scales, res.zeros) if aligned else None)
         recs.append(LinearRecord(
-            m.name, shape, 0.0, [], 0.0, 0, "skipped", 0.0))
+            m.name, shape, 0.0, [], 0.0, 0, "skipped"))
         _emit()
         return MemberResult(m.name, None, None)
     for li, lname in enumerate(m.lane_names):
         recs.append(LinearRecord(
             lname, shape, 0.0, [], 0.0, 0,
-            "rtn-fallback" if sv[li] else "skipped", 0.0))
+            "rtn-fallback" if sv[li] else "skipped"))
     _emit()
     if not sv.any():
         return MemberResult(m.name, None, None)

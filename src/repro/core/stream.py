@@ -78,6 +78,7 @@ import numpy as np
 
 from repro.config import Config, to_dict
 from repro.core import plan as qplan
+from repro.core import spans
 from repro.core.plan import LinearRecord, QuantReport
 
 PIPELINE_MODES = ("serial", "overlap")
@@ -114,7 +115,8 @@ class LayerStep:
 
     def resolve_params(self) -> Dict:
         if callable(self.params):
-            self.params = self.params()
+            with spans.span(spans.RESOLVE):
+                self.params = self.params()
         return self.params
 
     def release_params(self) -> None:
@@ -239,8 +241,12 @@ def _report_state(report: QuantReport, stats: Dict[str, Any]) -> Dict:
 
 def _restore_report(report: QuantReport, state: Dict,
                     stats: Dict[str, Any]) -> None:
+    # records of older checkpoints carry a per-linear ``seconds`` timer
+    # that the report no longer keeps
     report.linears[:] = [LinearRecord(**{**d, "shape": tuple(d["shape"])})
-                         for d in state.get("linears", [])]
+                         for d in ({k: v for k, v in d.items()
+                                    if k != "seconds"}
+                                   for d in state.get("linears", []))]
     report.seconds_stage1 = float(state.get("seconds_stage1", 0.0))
     report.seconds_stage2 = float(state.get("seconds_stage2", 0.0))
     report.layer_step_seconds[:] = state.get("layer_step_seconds", [])
@@ -372,80 +378,85 @@ def _run_items(cfg, walker, report, fwd_cache, mesh, verbose, qc, overlap,
                 save_fn(idx)
                 ckpt.wait()           # fences always flush
             continue
-        t_step = time.perf_counter()
-        hs = walker.streams[item.hs_slot]
-        # speculation eligibility is knowable up front (it only depends on
-        # the NEXT item's signature/slot), so the pre-quant outputs are
-        # retained exactly when the capture-ahead below will consume them.
-        # The repair-soundness predicate resolves lazily and only under
-        # overlap (short-circuit), materializing nxt's params at most one
-        # step early — they are about to be needed anyway.
-        nxt = items[idx + 1] if idx + 1 < len(items) else None
-        spec_block: Optional[str] = None
-        if overlap and nxt is not None:
-            if isinstance(nxt, StreamSwitch):
-                spec_block = "fence"
-            elif not use_spec:
-                spec_block = "eager_capture"
-            elif nxt.hs_slot != item.hs_slot:
-                spec_block = "cross_slot"
-            elif not _repair_sound(qpipe, nxt):
-                spec_block = "repair_unsound"
-        can_spec = overlap and nxt is not None and spec_block is None
-        # 1. capture — under overlap this re-propagates the taps on the
-        # repaired (post-scatter) stream: the exact Hessian repair of the
-        # speculative pass, riding its compiled entries.
-        cap = qpipe.capture_layer(cfg, item, hs, fwd_cache,
-                                  collect_h_out=can_spec)
-        routes = spec_routes if spec_for is item else None
-        if spec_for is item:
-            stats["repairs"] += 1
-        spec_for = None
-        spec_routes = None
-        # 2. plan — spec routing plans (if any) feed the MoE flip repair
-        new_params, dense_names, plan = qpipe.plan_layer(
-            cfg, item, cap, hs, report=report, stats=stats,
-            spec_routes=routes)
-        # 3. execute — async under overlap: per-stage sync and record
-        # materialization defer to this step's report boundary below.
-        deferred: Optional[List[Callable[[], None]]] = \
-            [] if overlap else None
-        results = qplan.execute_plan(qc, plan, report, mesh=mesh,
-                                     sync=not overlap, deferred=deferred)
-        # 4. scatter on-grid weights (+ grids) back into the subtree
-        qpipe.scatter_layer(new_params, dense_names, cap, results)
-        # 5. capture-ahead: dispatch the NEXT step's capture forward on
-        # THIS step's pre-quantization outputs while the executor is in
-        # flight. Discarded at the repair in (1) — overlap stays exact.
-        if can_spec:
-            spec_cap = qpipe.capture_layer(cfg, nxt, cap.h_out, fwd_cache,
-                                           speculative=True)
-            spec_for = nxt
-            spec_routes = spec_cap.spec_routes
-            stats["spec_captures"] += 1
-        elif spec_block is not None:
-            stats["serial_fallbacks"] += 1
-            stats["fallback_" + spec_block] += 1
-        # 6. propagate quantized activations
-        walker.streams[item.hs_slot] = qpipe.propagate_layer(
-            cfg, item, new_params, hs, fwd_cache)
-        item.store(new_params)
-        # 7. report boundary: materialize the deferred executor records
-        # and take the per-layer-step wall clock — the only sync in
-        # overlap mode (speculative work stays in flight across it).
-        item.release_params()    # drop the pre-quant slice progressively
-        if deferred:
-            for fin in deferred:
-                fin()
-        if overlap:
-            jax.block_until_ready(walker.streams[item.hs_slot][-1])
-        report.layer_step_seconds.append(time.perf_counter() - t_step)
-        stats["steps"] += 1
-        if ckpt is not None:
-            # step boundary: the step's artifacts + post-propagate stream
-            # state become durable (async; save() host-snapshots first,
-            # so in-flight speculative work keeps the device busy)
-            stored_snap[item.name] = new_params
-            save_fn(idx)
-        if verbose:
-            print(f"  {item.name}: {report.summary()}")
+        # one layer step; its self time is what no child span covers
+        with spans.span(spans.STEP, layer=idx):
+            t_step = time.perf_counter()
+            hs = walker.streams[item.hs_slot]
+            # speculation eligibility is knowable up front (it only depends
+            # on the NEXT item's signature/slot), so the pre-quant outputs
+            # are retained exactly when the capture-ahead below will consume
+            # them. The repair-soundness predicate resolves lazily and only
+            # under overlap (short-circuit), materializing nxt's params at
+            # most one step early — they are about to be needed anyway.
+            nxt = items[idx + 1] if idx + 1 < len(items) else None
+            spec_block: Optional[str] = None
+            if overlap and nxt is not None:
+                if isinstance(nxt, StreamSwitch):
+                    spec_block = "fence"
+                elif not use_spec:
+                    spec_block = "eager_capture"
+                elif nxt.hs_slot != item.hs_slot:
+                    spec_block = "cross_slot"
+                elif not _repair_sound(qpipe, nxt):
+                    spec_block = "repair_unsound"
+            can_spec = overlap and nxt is not None and spec_block is None
+            # 1. capture — under overlap this re-propagates the taps on the
+            # repaired (post-scatter) stream: the exact Hessian repair of
+            # the speculative pass, riding its compiled entries.
+            cap = qpipe.capture_layer(cfg, item, hs, fwd_cache,
+                                      collect_h_out=can_spec)
+            routes = spec_routes if spec_for is item else None
+            if spec_for is item:
+                stats["repairs"] += 1
+            spec_for = None
+            spec_routes = None
+            # 2. plan — spec routing plans (if any) feed the MoE flip repair
+            new_params, dense_names, plan = qpipe.plan_layer(
+                cfg, item, cap, hs, report=report, stats=stats,
+                spec_routes=routes)
+            # 3. execute — async under overlap: per-stage sync and record
+            # materialization defer to this step's report boundary below.
+            deferred: Optional[List[Callable[[], None]]] = \
+                [] if overlap else None
+            results = qplan.execute_plan(qc, plan, report, mesh=mesh,
+                                         sync=not overlap, deferred=deferred)
+            # 4. scatter on-grid weights (+ grids) back into the subtree
+            with spans.span(spans.SCATTER):
+                qpipe.scatter_layer(new_params, dense_names, cap, results)
+            # 5. capture-ahead: dispatch the NEXT step's capture forward on
+            # THIS step's pre-quantization outputs while the executor is in
+            # flight. Discarded at the repair in (1) — overlap stays exact.
+            if can_spec:
+                spec_cap = qpipe.capture_layer(cfg, nxt, cap.h_out,
+                                               fwd_cache, speculative=True)
+                spec_for = nxt
+                spec_routes = spec_cap.spec_routes
+                stats["spec_captures"] += 1
+            elif spec_block is not None:
+                stats["serial_fallbacks"] += 1
+                stats["fallback_" + spec_block] += 1
+            # 6. propagate quantized activations
+            with spans.span(spans.PROPAGATE):
+                walker.streams[item.hs_slot] = qpipe.propagate_layer(
+                    cfg, item, new_params, hs, fwd_cache)
+            item.store(new_params)
+            # 7. report boundary: materialize the deferred executor records
+            # and take the per-layer-step wall clock — the only sync in
+            # overlap mode (speculative work stays in flight across it).
+            item.release_params()    # drop the pre-quant slice progressively
+            if deferred:
+                with spans.span(spans.RESULTS):
+                    for fin in deferred:
+                        fin()
+            if overlap:
+                jax.block_until_ready(walker.streams[item.hs_slot][-1])
+            report.layer_step_seconds.append(time.perf_counter() - t_step)
+            stats["steps"] += 1
+            if ckpt is not None:
+                # step boundary: the step's artifacts + post-propagate
+                # stream state become durable (async; save() host-snapshots
+                # first, so in-flight speculative work keeps the device busy)
+                stored_snap[item.name] = new_params
+                save_fn(idx)
+            if verbose:
+                print(f"  {item.name}: {report.summary()}")

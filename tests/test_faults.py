@@ -275,6 +275,22 @@ class TestKillAndResume:
             with faults.inject("plan.stage2_executor@2"):
                 quantize_model(cfg, params, calib)
 
+    def test_restore_drops_the_timer_of_older_checkpoints(self):
+        """Records checkpointed with a per-linear ``seconds`` timer still
+        restore: the key is dropped."""
+        from repro.core import stream
+        rec = {"name": "mixer.q", "shape": [16, 32], "gptq_err": 0.5,
+               "gamma": [2.0, 1.0], "gamma_final": 1.0, "iters": 1,
+               "mode": "rpiq", "seconds": 0.25}
+        report = QuantReport()
+        stream._restore_report(report, {"linears": [rec],
+                                        "seconds_stage1": 1.5}, {})
+        r, = report.linears
+        assert dataclasses.asdict(r) == dict(
+            {k: v for k, v in rec.items() if k != "seconds"},
+            shape=(16, 32))
+        assert report.seconds_stage1 == 1.5
+
 
 # ---------------------------------------------------------------------------
 # hardened serving loop
