@@ -143,13 +143,15 @@ def _kernel_cases(*, tokens, d_model, d_ff, lanes, prefill, kv_heads,
                   (q, kc, ks, vc, vs, kpos),
                   lambda n, g, w: [_close(n, g, w, rtol=1e-5, atol=1e-5)]))
 
-    # stage 1 + stage 2 on a real calibration Hessian (d_model inputs: the
-    # d_ff-input linear's U does not fit VMEM and takes the counted
-    # budget fallback in the pipeline)
+    # stage 1 + stage 2 on a real calibration Hessian; stage 1 also at the
+    # d_ff input of the down projection, where U streams through VMEM
     x = normal((tokens, d_model))
     st = hess.accumulate(hess.init_hessian(d_model), x)
     hd = hess.damped(st, 0.01)
     u = hess.cholesky_inverse_upper(hd)
+    u_ff = hess.cholesky_inverse_upper(hess.damped(
+        hess.accumulate(hess.init_hessian(d_ff), normal((tokens, d_ff))),
+        0.01))
 
     def gptq_cmp(n, g, w):                           # test_gptq_kernel.py
         return [_close(f"{n} w_q", g[0], w[0], atol=1e-6),
@@ -157,10 +159,12 @@ def _kernel_cases(*, tokens, d_model, d_ff, lanes, prefill, kv_heads,
                 _close(f"{n} zeros", g[2], w[2], atol=1e-6),
                 _close(f"{n} err", g[3], w[3], rtol=1e-4)]
 
-    for b, out in ((3, d_model), (1, d_ff)):
-        w = normal((b, out, d_model), 0.05)
-        ub = jnp.broadcast_to(u, (b, d_model, d_model))
-        cases.append((f"gptq_block B{b} out{out} in{d_model}",
+    for b, out, inp, u_in in ((3, d_model, d_model, u),
+                              (1, d_ff, d_model, u),
+                              (1, d_model, d_ff, u_ff)):
+        w = normal((b, out, inp), 0.05)
+        ub = jnp.broadcast_to(u_in, (b, inp, inp))
+        cases.append((f"gptq_block B{b} out{out} in{inp}",
                       functools.partial(ops.gptq_block, impl="pallas"),
                       functools.partial(ops.gptq_block, impl="xla"),
                       (w, ub), gptq_cmp))

@@ -5,17 +5,20 @@
   - quant_pack     — fused quantize-to-grid + nibble pack (stage-2 projection
                      and deployment packing)
   - gptq_block     — the stage-1 GPTQ lazy-block sweep fused into ONE
-                     ``pallas_call``: grid (members, Cout tiles), the
-                     working row tile + the member's Cholesky factor stay
-                     VMEM-resident for the whole sweep, replacing the
-                     O(Cin) ``fori_loop``-of-``dynamic_slice`` XLA ops per
-                     sweep with a single kernel dispatch.  Dispatch
-                     contract (``ops.gptq_block``): ``impl="pallas"|"xla"``
-                     force a backend; ``"auto"`` uses pallas on TPU only
-                     when the per-cell VMEM residency
-                     ``4·Cin·(Cin + 2·block_out + blocksize)`` bytes fits
-                     the budget (Cin ≳ 1.7k f32 falls back to XLA); rows
-                     are padded to the ``block_out`` tile and sliced back.
+                     ``pallas_call``: grid (members, Cout tiles, lazy
+                     blocks), the working row tile stays VMEM-resident
+                     for the whole sweep while the member's Cholesky
+                     factor streams in one ``(blocksize, Cin)`` slab per
+                     lazy block, replacing the O(Cin)
+                     ``fori_loop``-of-``dynamic_slice`` XLA ops per sweep
+                     with a single kernel dispatch.  Dispatch contract
+                     (``ops.gptq_block``): ``impl="pallas"|"xla"`` force
+                     a backend; ``"auto"`` uses pallas on TPU when the
+                     row tile's residency
+                     ``4·Cin·(4·block_out + 3·blocksize)`` bytes + 1 MiB
+                     fits the budget (128-row tiles up to Cin = 3072),
+                     else XLA; rows are padded to the ``block_out`` tile
+                     and sliced back.
 
 ``ops`` is the dispatch layer (pallas on TPU / interpret-validated on CPU /
 XLA fallback); ``ref`` holds the pure-jnp/NumPy oracles used by the

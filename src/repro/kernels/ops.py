@@ -364,21 +364,34 @@ def quant_pack(w: jax.Array, scales: jax.Array, zeros: jax.Array, *,
 # Mosaic's default scoped-VMEM limit is 16 MiB per kernel (the v5e compiler
 # reports "limit 16.00M" when a kernel exceeds it); the budget keeps 4 MiB
 # of headroom under it. The per-kernel estimates below count every blocked
-# operand twice (the pipeline double-buffers them) and are upper bounds of
-# what the compiler allocates at opt-proxy's widths (tests/
-# test_tpu_compile.py compiles the dispatcher's own tiles).
+# operand twice (the pipeline double-buffers them, unless its BlockSpec asks
+# for one buffer) and are upper bounds of what the compiler allocates at
+# opt-proxy's widths (tests/test_tpu_compile.py compiles the dispatcher's
+# own tiles).
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
 def _gptq_vmem_bytes(block_out: int, in_dim: int, blocksize: int,
                      group_size: int) -> int:
-    """Per-cell residency: U (in², double-buffered), the double-buffered
-    w-in/w-out row tiles and lane-padded scale/zero/err outputs, the U row
-    slab and the rank-bs update temporary."""
+    """Per-cell residency of the streamed sweep: the w-in row tile (read
+    once, single-buffered), the double-buffered w-out tile and ``U`` row
+    slab, lane-padded scale/zero/err outputs, the tail update's
+    temporaries (the masked slab and the rank-bs product), and 1 MiB for
+    Mosaic's internal scratch and the column loop's carries (the v5e
+    compiler allocates 1.06-1.25 MiB beyond the tiles at every width)."""
     g_lanes = _round_up(in_dim // group_size, 128)
-    return 4 * (2 * in_dim * in_dim + 4 * block_out * in_dim
-                + blocksize * in_dim + block_out * in_dim
-                + 4 * block_out * g_lanes + 2 * block_out * 128)
+    return 4 * (4 * block_out * in_dim + 3 * blocksize * in_dim
+                + 4 * block_out * g_lanes + 2 * block_out * 128) + 2 ** 20
+
+
+def _gptq_block_out(out_dim: int, in_dim: int, blocksize: int,
+                    group_size: int, block_out: int | None = None
+                    ) -> tuple[int, bool]:
+    """The sweep's row tile (``block_out``, by default 128 rows or the rows
+    rounded up to 8) and whether its residency fits the VMEM budget."""
+    bo = block_out or min(128, _round_up(out_dim, 8))
+    return bo, (_gptq_vmem_bytes(bo, in_dim, blocksize, group_size)
+                <= _VMEM_BUDGET_BYTES)
 
 
 def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
@@ -394,12 +407,15 @@ def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
 
     ``impl``: "pallas" forces the fused kernel (interpret-mode off-TPU),
     "xla" the ``fori_loop``-of-``dynamic_slice`` reference body in
-    :mod:`repro.core.gptq`, and "auto" picks pallas on TPU only when the
-    per-cell VMEM residency (U + two row tiles) fits the budget — wide
-    layers (Cin ≳ 1.7k at f32) fall back to XLA instead of failing in
-    Mosaic.  ``interpret`` overrides the off-TPU interpret default (the
-    TPU-export path in benchmarks passes ``interpret=False`` to count the
-    kernel as the single XLA op it is on hardware).
+    :mod:`repro.core.gptq`, and "auto" picks pallas on TPU when the row
+    tile's per-cell VMEM residency fits the budget.  The kernel streams
+    ``U`` one ``(blocksize, Cin)`` lazy-block slab at a time, so a cell
+    holds ~``4·Cin·(4·block_out + 3·blocksize)`` bytes + 1 MiB: linear in
+    Cin, 11.9 MiB at Cin = 3072 with 128-row tiles.  Wider inputs fall
+    back to XLA, counted as ``gptq_block:vmem-budget``.
+    ``interpret`` overrides the off-TPU interpret default (the TPU-export
+    path in benchmarks passes ``interpret=False`` to count the kernel as
+    the single XLA op it is on hardware).
 
     ``local=True`` marks a per-shard call under :func:`gptq_block_sharded`'s
     ``shard_map``: the operands are device-local slabs, so "auto" skips the
@@ -412,7 +428,8 @@ def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
     out_dim, in_dim = w.shape[-2:]
     assert in_dim % blocksize == 0 and blocksize % group_size == 0, \
         (w.shape, blocksize, group_size)
-    bo = block_out or (128 if out_dim >= 128 else _round_up(out_dim, 8))
+    bo, fits = _gptq_block_out(out_dim, in_dim, blocksize, group_size,
+                               block_out)
     # Outside shard_map, "auto" stays on XLA in multi-device processes: the
     # documented GSPMD row-sharded path (gptq.py docstring, examples/
     # distributed_quantize.py) relies on XLA partitioning the pure-XLA
@@ -424,8 +441,7 @@ def gptq_block(w: jax.Array, hinv_u: jax.Array, *, bits: int = 4,
     use_pallas = impl == "pallas"
     if (impl == "auto" and _on_tpu()
             and (local or jax.device_count() == 1)):
-        if (_gptq_vmem_bytes(bo, in_dim, blocksize, group_size)
-                <= _VMEM_BUDGET_BYTES):
+        if fits:
             use_pallas = True
         else:
             _note_fallback("gptq_block", "vmem-budget")

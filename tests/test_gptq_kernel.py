@@ -69,6 +69,52 @@ class TestGPTQBlockKernel:
                                    atol=1e-6)
         np.testing.assert_allclose(float(err), float(core.err), rtol=1e-4)
 
+    def test_streams_u_over_lazy_blocks(self):
+        """(20, 512) in blocks of 128 with block_out = 8: U streams in four
+        row slabs through three zero-padded row tiles, and the scales,
+        zeros and Σerr² carried between lazy blocks match the XLA sweep
+        and the NumPy oracle."""
+        w, u = _problem(20, 512, seed=5)
+        kw = dict(bits=4, group_size=64, blocksize=128, symmetric=False)
+        w_q, s, z, err = kops.gptq_block(w, u, impl="pallas", block_out=8,
+                                         **kw)
+        core = _gptq_core(w, u, **kw)
+        wq_r, s_r, z_r, _ = ref.gptq_block_ref(np.asarray(w), np.asarray(u),
+                                               **kw)
+        assert w_q.shape == (20, 512) and s.shape == (20, 8)
+        for got, want in ((w_q, core.w_q), (s, core.scales),
+                          (z, core.zeros), (w_q, wq_r), (s, s_r), (z, z_r)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-6)
+        np.testing.assert_allclose(float(err), float(core.err), rtol=1e-4)
+
+    def test_row_tile_choice(self, monkeypatch):
+        """The streamed residency is linear in Cin: the 128-row tile fits
+        at in 768 and 3072 and not at 4096, short layers take their rows
+        rounded up to 8, an explicit block_out is the tile that is
+        checked, and a zero budget fits nothing, so "auto" on a TPU takes
+        the counted XLA fallback (no kernel runs here)."""
+        for in_dim in (768, 3072):
+            assert kops._gptq_block_out(768, in_dim, 128, 128) == (128, True)
+            assert kops._gptq_vmem_bytes(128, in_dim, 128, 128) <= \
+                kops._VMEM_BUDGET_BYTES
+        assert kops._gptq_block_out(768, 4096, 128, 128) == (128, False)
+        assert kops._gptq_block_out(20, 768, 128, 128) == (24, True)
+        assert kops._gptq_block_out(768, 3072, 128, 128,
+                                    block_out=256) == (256, False)
+        w, u = _problem(16, 128, seed=7)
+        monkeypatch.setattr(kops, "_VMEM_BUDGET_BYTES", 0)
+        assert kops._gptq_block_out(768, 768, 128, 128) == (128, False)
+        monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+        kops.reset_fallback_stats()
+        kw = dict(bits=4, group_size=64, blocksize=64)
+        with pytest.warns(RuntimeWarning, match="vmem-budget"):
+            w_q, *_ = kops.gptq_block(w, u, impl="auto", **kw)
+        assert kops.fallback_stats() == {"gptq_block:vmem-budget": 1}
+        np.testing.assert_array_equal(
+            np.asarray(w_q), np.asarray(kops.gptq_block(w, u, impl="xla",
+                                                        **kw)[0]))
+
     def test_batched_member_axis(self, stack_problem):
         """The stacked group slab maps onto the kernel's member grid axis:
         every lane matches the XLA batched path and per-member core."""
