@@ -105,20 +105,72 @@ def _note_fallback(op: str, reason: str) -> None:
 # H += X^T X
 # ---------------------------------------------------------------------------
 
+# Small Hessian tiles take thousands of grid steps and re-read ``x`` for
+# every output tile: at 128-edge tiles a d-3072 call ran 5-9x its MXU
+# time on a v5e (PERF.md §5, §6). So the tiles are as large as the chip's
+# VMEM allows: the kernel raises Mosaic's scoped limit to
+# _HESSIAN_VMEM_LIMIT_BYTES and the tiles' residency stays 4 MiB under it.
+_HESSIAN_MAX_EDGE = 1024
+_HESSIAN_MAX_SLAB = 2048
+_HESSIAN_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+_HESSIAN_VMEM_BUDGET_BYTES = _HESSIAN_VMEM_LIMIT_BYTES - 4 * 1024 * 1024
+
+
+def _hessian_vmem_bytes(block_d: int, block_n: int, d: int) -> int:
+    """Residency of one grid step: the double-buffered ``x`` slabs (one
+    operand for the whole-square tile, two otherwise), the output tile
+    (single-buffered where one tile covers the square and never moves,
+    double-buffered otherwise), and Mosaic's scratch for the dot, 2 bytes
+    a slab element plus 1 MiB (the compiler for a described v5e allocates
+    1.7-2.2 bytes a slab element beyond the tiles)."""
+    tiles = (4 * (d * d + 2 * block_n * d) if block_d == d
+             else 4 * (2 * block_d * block_d + 4 * block_n * block_d))
+    return tiles + 2 * block_n * block_d + 2 ** 20
+
+
+def _hessian_tiles(n: int, d: int) -> tuple[int, int]:
+    """``(block_d, block_n)`` for x (n, d), from the shape alone.
+
+    The tile edge is the largest multiple of 128 that divides the
+    128-rounded width and is at most ``_HESSIAN_MAX_EDGE`` (the whole
+    width below 128), so no width is padded beyond 128. The token slab is
+    the deepest power of two up to ``_HESSIAN_MAX_SLAB`` whose residency
+    fits the budget, then evened out over the slabs ``n`` needs, so the
+    token padding stays under 8 rows a slab."""
+    if d < 128:
+        block_d = d
+    else:
+        d = _round_up(d, 128)
+        edges = range(128, min(d, _HESSIAN_MAX_EDGE) + 1, 128)
+        block_d = max(e for e in edges if d % e == 0)
+    slab = _HESSIAN_MAX_SLAB
+    while slab > 8 and (_hessian_vmem_bytes(block_d, slab, d)
+                        > _HESSIAN_VMEM_BUDGET_BYTES):
+        slab //= 2
+    steps = -(-n // slab)
+    return block_d, _round_up(-(-n // steps), 8)
+
+
 def hessian_accum(x: jax.Array, *, impl: str = "auto",
                   interpret: bool | None = None) -> jax.Array:
     """Gram matrix X^T X with fp32 accumulation. x: (n, d). ``interpret``
-    overrides the off-TPU interpret default, as in :func:`gptq_block`."""
+    overrides the off-TPU interpret default, as in :func:`gptq_block`.
+
+    The kernel's tiles follow from ``(n, d)`` (:func:`_hessian_tiles`):
+    square output tiles of the largest 128-multiple edge up to 1024 that
+    divides the 128-rounded width (d 768 is one whole tile, read from a
+    single ``x`` operand; d 3072 takes 1024-edge tiles), and token slabs as
+    deep as the raised VMEM limit lets them be, up to 2048."""
     if impl == "xla" or (impl == "auto" and not _on_tpu()):
         return ref.hessian_accum_ref(x)
     n, d = x.shape
-    block_n = 512 if n >= 512 else max(8, n)
-    block_d = 128 if d >= 128 else d
+    block_d, block_n = _hessian_tiles(n, d)
     n_pad, d_pad = _round_up(n, block_n), _round_up(d, block_d)
     if (n_pad, d_pad) != (n, d):
         x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
     H = hessian_accum_pallas(
         x, block_d=block_d, block_n=block_n,
+        vmem_limit_bytes=_HESSIAN_VMEM_LIMIT_BYTES,
         interpret=(not _on_tpu()) if interpret is None else interpret)
     return H[:d, :d]
 

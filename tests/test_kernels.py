@@ -4,6 +4,8 @@ Every kernel is executed with interpret=True (kernel body runs in Python on
 CPU) and compared against ref.py. Block-shape edge cases (non-divisible
 sizes exercised through the ops.py padding wrappers) are included.
 """
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,7 @@ import pytest
 from _hypothesis_shim import given, settings, st
 
 from repro.core.quant import pack_quantized
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.hessian_accum import hessian_accum_pallas
 from repro.kernels.quant_pack import quant_pack_pallas
 from repro.kernels.selective_scan import selective_scan_pallas
@@ -26,6 +28,10 @@ class TestHessianKernel:
     @pytest.mark.parametrize("n,d,bn,bd", [
         (128, 128, 64, 64), (256, 128, 128, 128), (512, 256, 256, 128),
         (64, 64, 32, 32),
+        (1024, 256, 256, 128),    # multi-tile grid (2, 2, 4)
+        (512, 256, 128, 256),     # one whole tile: x read once, grid (4,)
+        (256, 384, 128, 384),     # whole tile, edge not a power of two
+        (512, 768, 256, 384),     # multi-tile grid at a 384 edge
     ])
     def test_shapes(self, n, d, bn, bd):
         x = _rand((n, d), n + d)
@@ -48,6 +54,59 @@ class TestHessianKernel:
         H = hessian_accum_pallas(x, block_d=64, block_n=128, interpret=True)
         evs = np.linalg.eigvalsh(np.asarray(H))
         assert evs.min() > -1e-3
+
+    @pytest.mark.parametrize("n,d", [
+        (100, 200),     # slab 104 deeper than n; width padded to 256
+        (1000, 384),    # whole 384 tile, one 1000-token slab
+        (3, 64),        # tiny width: the whole (64, 64) square
+    ])
+    def test_dispatch(self, n, d):
+        x = _rand((n, d), n * d)
+        out = ops.hessian_accum(x, impl="pallas", interpret=True)
+        assert out.shape == (d, d)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(ref.hessian_accum_ref(x)),
+                                   rtol=1e-4, atol=1e-3)
+
+
+def _registry_hessian_shapes():
+    """(tokens, width) of every dense linear input the registry's
+    configurations calibrate: d_model and d_ff at the decoder's calibration
+    tokens (and the encoder's), plus the benchmark's d 768 and d 3072."""
+    from repro.configs.registry import ARCH_IDS, get_config
+    shapes = {(8192, 768), (8192, 3072)}
+    for arch in ARCH_IDS:
+        cfg = get_config(arch)
+        m, q = cfg.model, cfg.quant
+        tokens = [q.calib_batch_size * min(q.calib_seq_len,
+                                           m.max_seq_len - 8)]
+        if m.is_encoder_decoder:
+            tokens.append(q.calib_batch_size * m.encoder_seq_len)
+        shapes |= {(n, d) for n in tokens for d in (m.d_model, m.d_ff)
+                   if d > 0}
+    return sorted(shapes)
+
+
+def test_hessian_tile_choice():
+    """The dispatcher's tiles: an edge that divides the 128-rounded width
+    (so no padding beyond 128), a residency within the VMEM budget under
+    the limit the kernel is given, little token padding, and nothing but
+    the shape behind the choice."""
+    shapes = _registry_hessian_shapes()
+    assert list(inspect.signature(ops._hessian_tiles).parameters) == \
+        ["n", "d"]
+    first = {s: ops._hessian_tiles(*s) for s in shapes}
+    assert first == {s: ops._hessian_tiles(*s) for s in reversed(shapes)}
+    assert ops._HESSIAN_VMEM_BUDGET_BYTES <= ops._HESSIAN_VMEM_LIMIT_BYTES
+    for (n, d), (bd, bn) in first.items():
+        d_pad = -(-d // 128) * 128
+        assert bd % 128 == 0 and d_pad % bd == 0, (n, d, bd)
+        assert bd <= ops._HESSIAN_MAX_EDGE, (n, d, bd)
+        assert bn % 8 == 0 and bn <= ops._HESSIAN_MAX_SLAB, (n, d, bn)
+        slabs = -(-n // bn)
+        assert slabs * bn - n < 8 * slabs, (n, d, bn)
+        assert (ops._hessian_vmem_bytes(bd, bn, d_pad)
+                <= ops._HESSIAN_VMEM_BUDGET_BYTES), (n, d, bd, bn)
 
 
 class TestW4A16Kernel:

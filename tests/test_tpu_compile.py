@@ -7,7 +7,8 @@ VMEM than a kernel may use. These tests lower each kernel through its
 hand-chosen ones — with ``interpret=False`` against a described (not
 attached) ``v5e:2x2`` topology, at opt-proxy full's widths: d_model 768,
 d_ff 3072, 12 heads of 64, a 16 x 512-token calibration batch, 8 decode
-lanes and 128-token prefill.
+lanes and 128-token prefill; the Hessian also at OPT-6.7B's d_model 4096
+and h2o-danube's d_ff 6912.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and a test file that decided at
@@ -23,8 +24,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.kernels.hessian_accum import hessian_accum_pallas
 
 D_MODEL, D_FF, HEADS, HEAD_DIM = 768, 3072, 12, 64
+OPT_6_7B_D_MODEL = 4096     # a width the paper quantizes
+# h2o-danube's d_ff: 768-edge tiles on a 9 x 9 grid, the largest residency
+# the Hessian's tile chooser picks at any registry width
+WIDE_D_FF = 6912
 TOKENS = 16 * 512           # one calibration batch
 LANES, PREFILL = 8, 128
 CAP = 128 + 32 + 1          # prompt + new tokens + 1 (the serving cache)
@@ -57,9 +63,16 @@ def _assert_kernel(text, n=1):
     assert text.count("tpu_custom_call") >= n, "no Mosaic kernel in the HLO"
 
 
-@pytest.mark.parametrize("d", [D_MODEL, D_FF])
+@pytest.mark.parametrize("d", [D_MODEL, D_FF, OPT_6_7B_D_MODEL,
+                               WIDE_D_FF])
 def test_hessian_accum(one_chip, d):
     fn = functools.partial(ops.hessian_accum, impl="pallas", interpret=False)
+    _assert_kernel(_compile(one_chip, fn, ((TOKENS, d), jnp.float32)))
+    # the dispatcher's VMEM estimate bounds what the compiler allocates
+    bd, bn = ops._hessian_tiles(TOKENS, d)
+    est = ops._hessian_vmem_bytes(bd, bn, d)
+    fn = functools.partial(hessian_accum_pallas, block_d=bd, block_n=bn,
+                           vmem_limit_bytes=est, interpret=False)
     _assert_kernel(_compile(one_chip, fn, ((TOKENS, d), jnp.float32)))
 
 
