@@ -1,10 +1,6 @@
 """Shared benchmark helpers: train a small LM, evaluate PPL/accuracy."""
 from __future__ import annotations
 
-import os
-import time
-from typing import Dict, Tuple
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,11 +18,6 @@ def bench_config(arch: str = "opt-proxy", **model_over) -> Config:
     for k, v in model_over.items():
         setattr(cfg.model, k, v)
     cfg.model.__post_init__()
-    # CI smoke hook (scripts/check.sh): force the layer-walk schedule for
-    # every benchmarked quantize_model run, e.g. REPRO_BENCH_PIPELINE=overlap
-    pl = os.environ.get("REPRO_BENCH_PIPELINE")
-    if pl:
-        cfg.quant.pipeline = pl
     return cfg
 
 

@@ -18,10 +18,20 @@ is the measured truth, not a bug.
 from __future__ import annotations
 
 import jax
+import numpy as np
 
-from benchmarks.common import bench_config, cache_bytes_per_seq, param_bytes
+from benchmarks.common import bench_config, cache_bytes_per_seq
 from repro.configs import ARCH_IDS, get_config
-from repro.launch.hlo_analysis import total_param_count
+from repro.models import transformer as T
+
+
+def total_param_count(mc) -> int:
+    """All parameters (MoE counts every expert), from the shapes the real
+    param constructors return under ``jax.eval_shape`` — nothing is
+    materialized."""
+    init = T.init_encdec_params if mc.is_encoder_decoder else T.init_params
+    tree = jax.eval_shape(lambda: init(mc, jax.random.PRNGKey(0)))
+    return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(tree))
 
 
 def run() -> list:

@@ -4,8 +4,7 @@
 
 Quantizes the chosen architecture's smoke config with RPIQ, packs to int4
 (≈ 23% of the bf16 weight bytes incl. scales), and serves a batch of
-prompts through prefill + jit'd decode — the exact serve_step the multi-pod
-dry-run lowers at scale.
+prompts through prefill + jit'd decode (``serving.engine.serve_step``).
 """
 import argparse
 import os

@@ -28,8 +28,7 @@ PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
 
 # multi-host-device leg: sharded group execution parity on a forced
 # 4-device host mesh (tests/test_plan_sharded.py skips in the
-# single-device run above — the main process must keep the real device
-# for the dry-run contract)
+# single-device run above — the main process keeps the one real device)
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
   PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
   python -m pytest -x -q tests/test_plan_sharded.py
@@ -59,35 +58,16 @@ PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python scripts/resume_smoke.py
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
   python scripts/chaos_soak.py --seeds 0,1,2 --smoke
 
-# benchmark smoke: the quantization hot path must stay runnable end to end —
-# table4 covers the executor/dispatch story, table5 the stage-2 convergence
-# path (Γ trajectories + early stop) on both curvature modes.
-# (--tiny deliberately does NOT rewrite the repo-root BENCH_table4.json —
-# refresh the trajectory with a full `benchmarks.run table4` when perf moves)
-PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run table4 --tiny
+# stage-2 convergence smoke: Γ trajectories + early stop on both
+# curvature modes (table5)
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run table5 --tiny
-
-# perf-regression gate: the committed BENCH_table4.json trajectory must
-# keep the >=10x fused-kernel op-count ratios AND show the routed-MoE
-# overlap rows still speculating (flip repair, not serial re-planning) —
-# including the expert-sharded cell (scripts/check_bench.py)
-PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python scripts/check_bench.py
-
-# 671B-shape lowering smoke: the deepseek-v3-671b routed-MoE quantization
-# cell (capture -> stage-1 -> stage-2 -> quantized-decode serve) must keep
-# lowering on the 512-way forced host mesh with the expert-parallel
-# quant mesh (launch/dryrun.py --quant-cell; lowering only, no compile —
-# the full artifact lives in artifacts/dryrun/, EXPERIMENTS.md §Dry-run)
-PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
-  python -m repro.launch.dryrun --quant-cell --arch deepseek-v3-671b \
-  --quant-mesh 1x2x256 --out artifacts/dryrun
 
 # overlap-pipeline smoke: the streaming layer-walk scheduler
 # (quant.pipeline=overlap, core/stream.py) must stay runnable end to end
-# on the same tiny table4 leg (parity itself is pinned in
-# tests/test_pipeline_stream.py; this guards the bench/launch plumbing)
-REPRO_BENCH_PIPELINE=overlap \
-  PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.run table4 --tiny
+# through the quantize entry point (parity itself is pinned in
+# tests/test_pipeline_stream.py)
+PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
+  python -m repro.launch.quantize --arch opt-proxy --smoke quant.pipeline=overlap
 
 # serving smoke: static-vs-continuous A/B bench path end to end
 # (scheduler parity is pinned in tests/test_serving.py; --tiny does NOT

@@ -19,9 +19,8 @@ import sys
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 _MD_REF = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_/.-]*\.md\b")
 
-# placeholder/generated names, not citations: the docstring example
-# above, and the bench's generated perf summary (untracked output)
-_IGNORE = {"SOMENAME.md", "artifacts/perf_summary.md"}
+# placeholder names, not citations: the docstring example above
+_IGNORE = {"SOMENAME.md"}
 
 
 def _scan_files():

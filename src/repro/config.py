@@ -5,15 +5,14 @@ Plain dataclasses + dict overrides + a tiny CLI layer. No external deps.
 Every launchable entry point takes ``--arch <id>`` (resolved through
 ``repro.configs.registry``) plus ``key=value`` dotted overrides, e.g.::
 
-    python -m repro.launch.dryrun --arch minicpm-2b --shape train_4k \
-        parallel.sp=true quant.bits=4
+    python -m repro.launch.quantize --arch opt-proxy --smoke \
+        quant.bits=4 quant.pipeline=overlap
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 from dataclasses import dataclass, field, fields, is_dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 
 # ---------------------------------------------------------------------------
@@ -109,11 +108,6 @@ class ModelConfig:
     mtp_depth: int = 0
     mtp_loss_weight: float = 0.3
 
-    # beyond-paper perf toggles (§Perf hillclimb; False = naive baseline)
-    opt_attention: bool = True      # bf16 cache/score einsums, no repeat_kv
-    #                                 materialization (measured 2-2.5×
-    #                                 decode/train memory-term win)
-
     def __post_init__(self):
         if self.head_dim == 0:
             self.head_dim = self.d_model // self.num_heads
@@ -126,11 +120,6 @@ class ModelConfig:
     def layer_kinds(self) -> Tuple[str, ...]:
         pat = self.block_pattern
         return tuple(pat[i % len(pat)] for i in range(self.num_layers))
-
-    def is_subquadratic(self) -> bool:
-        """True if the arch supports unbounded-context decode with bounded state."""
-        kinds = set(self.layer_kinds)
-        return kinds.issubset({"swa", "local", "rglru", "mamba"})
 
 
 # ---------------------------------------------------------------------------
@@ -154,9 +143,6 @@ class ParallelConfig:
     grad_compression: str = "none"  # none | bf16 | int8 (explicit-DP mode)
     int8_optimizer_state: bool = False
     overlap_collectives: bool = True   # XLA latency hiding scheduler hints
-    ep_local_dispatch: bool = True  # shard_map MoE routing per data shard
-    #                                 (False = pure-GSPMD global dispatch —
-    #                                 the §Perf cell-B baseline)
 
 
 @dataclass
@@ -191,14 +177,6 @@ class QuantConfig:
     #                                 on TPU when the row tile + instance
     #                                 slab + block inverses fit VMEM, else
     #                                 xla)
-    jit_capture: bool = True        # jit the per-layer calibration forward
-    #                                 (capture + propagate), cached per layer
-    #                                 signature within one quantize_model
-    #                                 run; False = legacy eager forwards
-    batched_executor: bool = True   # group same-shape linears into vmapped
-    #                                 GPTQ+RPIQ plan dispatches (core/plan.py);
-    #                                 False = legacy per-linear dispatch
-    #                                 (table4 baseline, parity tests)
     mesh: str = "off"               # sharded group execution (DESIGN.md
     #                                 §2.6): "off" = single device (default),
     #                                 "auto" = all devices on the data axis,

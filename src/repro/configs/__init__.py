@@ -1,3 +1,2 @@
 """Architecture registry: one module per assigned architecture."""
-from repro.configs.registry import (ARCH_IDS, get_config, input_shapes,
-                                    shape_names_for)  # noqa: F401
+from repro.configs.registry import ARCH_IDS, get_config  # noqa: F401

@@ -3,9 +3,6 @@
 61L, d_model 7168, 128 heads, per-expert d_ff 2048, vocab 129280, first 3
 layers dense (d_ff 18432), MLA ranks (q 1536 / kv 512, nope 128 / rope 64 /
 v 128), one MTP depth. [arXiv:2412.19437; hf].
-
-bf16 master params + int8 Adam moments (parallel.int8_optimizer_state) keep
-the train_4k cell inside v5e HBM at 512 chips — see EXPERIMENTS.md §Dry-run.
 """
 from repro.config import Config, MLAConfig, ModelConfig, MoEConfig
 

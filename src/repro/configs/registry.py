@@ -1,23 +1,13 @@
-"""--arch resolution + the assigned input-shape grid.
+"""--arch resolution.
 
 Every architecture module exposes ``full()`` and ``smoke()`` returning a
 :class:`repro.config.Config`. ``smoke`` is a reduced same-family config that
 runs a forward/train step on CPU in seconds; ``full`` is the published
-configuration, exercised only through the dry-run (ShapeDtypeStruct).
-
-Shapes (assigned grid, LM family):
-  train_4k     seq 4096  × global_batch 256   → train_step
-  prefill_32k  seq 32768 × global_batch 32    → prefill forward
-  decode_32k   cache 32768 × global_batch 128 → serve_step (1 new token)
-  long_500k    cache 524288 × global_batch 1  → serve_step; sub-quadratic
-               archs only (SWA / RG-LRU hybrid / SSM) — pure full-attention
-               archs are recorded N/A-by-design (DESIGN.md §3).
+configuration.
 """
 from __future__ import annotations
 
-import dataclasses
 import importlib
-from typing import Dict, List, Optional
 
 from repro.config import Config
 
@@ -41,22 +31,6 @@ _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")
             for a in ARCH_IDS}
 
 
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str          # "train" | "prefill" | "decode"
-    seq_len: int
-    global_batch: int
-
-
-SHAPES: Dict[str, ShapeSpec] = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-
 def get_config(arch: str, smoke: bool = False) -> Config:
     if arch not in _MODULES:
         raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS}")
@@ -64,16 +38,3 @@ def get_config(arch: str, smoke: bool = False) -> Config:
     cfg: Config = mod.smoke() if smoke else mod.full()
     cfg.model.__post_init__()
     return cfg
-
-
-def shape_names_for(arch: str) -> List[str]:
-    """The assigned shape cells for this arch (long_500k gated)."""
-    cfg = get_config(arch)
-    names = ["train_4k", "prefill_32k", "decode_32k"]
-    if cfg.model.is_subquadratic():
-        names.append("long_500k")
-    return names
-
-
-def input_shapes(arch: str, shape: str) -> ShapeSpec:
-    return SHAPES[shape]

@@ -8,11 +8,10 @@ the paper assumes:
      a. **capture** — run the layer over all batches with a :class:`Tap`
         that streams each named linear's inputs into its Hessian
         (eq. 9, ``H += X_bᵀX_b``) and keeps only the **last** batch's
-        inputs resident (single-instance paradigm, eq. 11). With
-        ``quant.jit_capture`` (default) the forward is COMPILED — the tap
-        collects tracers inside the jit and the inputs come back as
-        outputs — and cached per layer signature, so repeated layers
-        reuse the compiled forward (``False`` = legacy eager capture);
+        inputs resident (single-instance paradigm, eq. 11). The forward
+        is COMPILED — the tap collects tracers inside the jit and the
+        inputs come back as outputs — and cached per layer signature, so
+        repeated layers reuse the compiled forward;
      b. **plan** — :func:`repro.core.plan.build_plan` turns the captured
         linears (dense taps AND stacked MoE expert slices) into a
         :class:`~repro.core.plan.QuantPlan`: members grouped by
@@ -21,8 +20,7 @@ the paper assumes:
         (``gptq_quantize_batched`` stage 1, eq. 10; ``rpiq_refine_batched``
         stage 2, eq. 4–8, 12–14, 19–23): weights/Hessians/instances are
         stacked on a leading axis and quantized in ONE dispatch per stage
-        per group instead of one per linear (``quant.batched_executor=False``
-        restores per-linear dispatch — same plan, singleton executors);
+        per group instead of one per linear;
      d. **scatter** the on-grid results back into the param tree and re-run
         the layer to **propagate quantized activations** to the next layer
         (so later Hessians see the quantized network — GPTQ semantics);
@@ -81,9 +79,7 @@ from repro.models.layers import embed, norm, sinusoidal_positions
 # ---------------------------------------------------------------------------
 # Jitted calibration forward (capture + propagate)
 #
-# The capture/propagate forwards used to run eagerly, op by op — the
-# second wall-clock dominator after the executors (benchmarks/
-# table4_time.py).  ``_layer_forward_jit`` compiles them instead: the Tap
+# ``_layer_forward_jit`` compiles the capture/propagate forwards: the Tap
 # opens INSIDE the traced function in collect-tracers mode, so the tapped
 # layer inputs come back as ordinary jit outputs.  Entries are cached per
 # (fwd_key, batch index, layer-signature) for ONE ``quantize_model`` run
@@ -379,8 +375,7 @@ class CaptureResult:
 
 @spans.spanned(spans.CAPTURE)
 def capture_layer(cfg: Config, step: LayerStep, hs: List[jax.Array],
-                  fwd_cache: Optional[Dict] = None,
-                  speculative: bool = False,
+                  fwd_cache: Dict, speculative: bool = False,
                   collect_h_out: bool = False) -> CaptureResult:
     """Stage (a): stream Hessians over all batches, keep last inputs.
 
@@ -394,9 +389,7 @@ def capture_layer(cfg: Config, step: LayerStep, hs: List[jax.Array],
     pre-quantization stream the scheduler speculates on).
     """
     faults.fire("stream.capture_forward")
-    qc = cfg.quant
     layer_params = step.resolve_params()
-    use_jit = qc.jit_capture and fwd_cache is not None
     is_moe = _is_moe_layer(layer_params)
     hessians: Dict[str, hess.HessianState] = {}
     last_x: Dict[str, jax.Array] = {}
@@ -425,16 +418,12 @@ def capture_layer(cfg: Config, step: LayerStep, hs: List[jax.Array],
 
     h_out: Optional[List[jax.Array]] = [] if collect_h_out else None
     for bi, h in enumerate(hs):
-        if use_jit:
-            out, recs = _layer_forward_jit(fwd_cache, step.fwd_key,
-                                           step.apply_fn, layer_params, h,
-                                           bi, step.batch_dependent)
-            for name, xs in recs.items():
-                for x in xs:
-                    on_record(name, x)
-        else:
-            with Tap(on_record=on_record):
-                out = step.apply_fn(layer_params, h, bi)
+        out, recs = _layer_forward_jit(fwd_cache, step.fwd_key,
+                                       step.apply_fn, layer_params, h, bi,
+                                       step.batch_dependent)
+        for name, xs in recs.items():
+            for x in xs:
+                on_record(name, x)
         if collect_h_out:
             h_out.append(out)
     spec_routes: Optional[List] = None
@@ -498,20 +487,17 @@ def scatter_layer(new_params: Dict, dense_names: List[str],
     return new_params
 
 
-def propagate_layer(cfg: Config, step: LayerStep, new_params: Dict,
+def propagate_layer(step: LayerStep, new_params: Dict,
                     hs: List[jax.Array],
-                    fwd_cache: Optional[Dict] = None) -> List[jax.Array]:
+                    fwd_cache: Dict) -> List[jax.Array]:
     """Stage (d, second half): re-run the layer with quantized params so
     the next layer's Hessians see the quantized network (same compiled
     forward family; the quantized params carry extra grid leaves, so they
     key their own cross-layer cache entry)."""
-    use_jit = cfg.quant.jit_capture and fwd_cache is not None
-    if use_jit:
-        return [_layer_forward_jit(fwd_cache, step.fwd_key, step.apply_fn,
-                                   new_params, h, bi, step.batch_dependent,
-                                   collect=False)[0]
-                for bi, h in enumerate(hs)]
-    return [step.apply_fn(new_params, h, bi) for bi, h in enumerate(hs)]
+    return [_layer_forward_jit(fwd_cache, step.fwd_key, step.apply_fn,
+                               new_params, h, bi, step.batch_dependent,
+                               collect=False)[0]
+            for bi, h in enumerate(hs)]
 
 
 def quantize_layer(cfg: Config, layer_params: Dict, hs: List[jax.Array],
@@ -527,8 +513,11 @@ def quantize_layer(cfg: Config, layer_params: Dict, hs: List[jax.Array],
     batch_index) -> h_out`` runs the layer; ``mesh`` forwards to
     :func:`repro.core.plan.execute_plan` for sharded group execution
     (capture itself stays single-device — only executor work scales with
-    the mesh). Returns (new_layer_params, new_hs).
+    the mesh). Without ``fwd_cache`` the compiled forwards live for this
+    call only. Returns (new_layer_params, new_hs).
     """
+    if fwd_cache is None:
+        fwd_cache = ForwardCache()
     step = LayerStep(name="layer", params=layer_params, apply_fn=apply_fn,
                      hs_slot="h", fwd_key=fwd_key, store=lambda p: None,
                      batch_dependent=batch_dependent)
@@ -537,7 +526,7 @@ def quantize_layer(cfg: Config, layer_params: Dict, hs: List[jax.Array],
                                                report=report)
     results = qplan.execute_plan(cfg.quant, plan, report, mesh=mesh)
     scatter_layer(new_params, dense_names, cap, results)
-    return new_params, propagate_layer(cfg, step, new_params, hs, fwd_cache)
+    return new_params, propagate_layer(step, new_params, hs, fwd_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -725,7 +714,7 @@ def quantize_model(cfg: Config, params: Dict,
             from repro.launch.mesh import make_quant_mesh
             mesh = make_quant_mesh(cfg.quant.mesh)
 
-        fwd_cache = ForwardCache()   # per-run compiled forwards (jit_capture)
+        fwd_cache = ForwardCache()   # per-run compiled forwards
         build = (_walker_encdec if cfg.model.is_encoder_decoder
                  else _walker_decoder_only)
         with spans.span(spans.WALKER):

@@ -24,11 +24,7 @@ their group as a **mask**: the batched RTN fallback is computed for the
 whole stack (row-wise, nearly free) and selected per member with
 ``jnp.where`` — no per-expert Python loop. Members whose input dim doesn't
 align to the grid are carried on a per-member fallback list (skip, or
-full-row RTN for starved experts), exactly the legacy semantics.
-
-``execute_plan(..., batched=False)`` runs the same plan through the
-singleton executors (one dispatch per linear) — the pre-plan reference
-path kept for parity tests and the table4 per-linear-vs-batched benchmark.
+full-row RTN for starved experts).
 
 **Sharded group execution** (``execute_plan(..., mesh=...)``, DESIGN.md
 §2.6): each group's stacked slab is embarrassingly parallel over lanes AND
@@ -57,10 +53,9 @@ from repro.config import QuantConfig
 from repro.core import faults
 from repro.core import hessian as hess
 from repro.core import spans
-from repro.core.gptq import (GPTQResult, gptq_quantize,
-                             gptq_quantize_batched, rtn_quantize,
-                             rtn_quantize_batched)
-from repro.core.rpiq import RPIQResult, rpiq_refine, rpiq_refine_batched
+from repro.core.gptq import (GPTQResult, gptq_quantize_batched,
+                             rtn_quantize, rtn_quantize_batched)
+from repro.core.rpiq import RPIQResult, rpiq_refine_batched
 from repro.distributed.sharding import (QuantGroupSharding,
                                         quant_group_sharding)
 from repro.kernels import ops as kops
@@ -648,77 +643,6 @@ def _group_results(ms: List[PlanMember], report: QuantReport,
     return results
 
 
-def _lane_view(m: PlanMember, li: int) -> "PlanMember":
-    """Singleton view of one lane of a stacked member (legacy path only)."""
-    if not m.stacked:
-        return m
-    xc = None if m.x_count is None else \
-        jnp.asarray(m.x_count, jnp.int32).reshape(-1)[li]
-    return PlanMember(m.lane_names[li], m.w_oi[li],
-                      hess.HessianState(m.hessian.H[li],
-                                        jnp.asarray(m.hessian.count,
-                                                    jnp.int32
-                                                    ).reshape(-1)[li]),
-                      m.x_last[li], x_count=xc,
-                      starved=bool(m.starved_mask()[li]))
-
-
-def _execute_member_singleton(qc: QuantConfig, m: PlanMember,
-                              report: QuantReport, rpiq_enabled: bool
-                              ) -> MemberResult:
-    """Legacy per-linear path: one dispatch per lane, per stage."""
-    if m.stacked:
-        parts = [_execute_member_singleton(qc, _lane_view(m, li), report,
-                                           rpiq_enabled)
-                 for li in range(m.lanes)]
-        return MemberResult(m.name,
-                            jnp.stack([p.w_q for p in parts]),
-                            (jnp.stack([p.grid[0] for p in parts]),
-                             jnp.stack([p.grid[1] for p in parts])))
-    shape = m.wshape
-    if m.starved:
-        res = rtn_quantize(jnp.asarray(m.w_oi, jnp.float32), bits=qc.bits,
-                           group_size=qc.group_size, symmetric=qc.symmetric)
-        report.linears.append(LinearRecord(
-            m.name, shape, 0.0, [], 0.0, 0, "rtn-fallback"))
-        return MemberResult(m.name, res.w_q, (res.scales, res.zeros))
-    t0 = time.perf_counter()
-    with spans.span(spans.STAGE1):
-        w_oi = jnp.asarray(m.w_oi, jnp.float32)
-        hd = hess.damped(m.hessian, qc.percdamp)
-        u = hess.cholesky_inverse_upper(hd)
-        res1 = gptq_quantize(w_oi, u, bits=qc.bits, group_size=qc.group_size,
-                             blocksize=qc.blocksize, symmetric=qc.symmetric,
-                             impl=qc.gptq_impl)
-        jax.block_until_ready(res1.w_q)
-    t1 = time.perf_counter()
-    report.seconds_stage1 += t1 - t0
-    grid = (res1.scales, res1.zeros)
-    if not rpiq_enabled or qc.rpiq_iters <= 0:
-        report.linears.append(LinearRecord(
-            m.name, shape, float(res1.err), [], 0.0, 0, "gptq"))
-        return MemberResult(m.name, res1.w_q, grid)
-    with spans.span(spans.STAGE2):
-        res2 = rpiq_refine(res1.w_q, w_oi,
-                           jnp.asarray(m.x_last, jnp.float32), hd,
-                           res1.scales, res1.zeros,
-                           h_count=m.hessian.count, x_count=m.x_count,
-                           bits=qc.bits, group_size=qc.group_size,
-                           block_size=qc.blocksize, alpha=qc.rpiq_alpha,
-                           t_max=qc.rpiq_iters,
-                           early_stop=qc.rpiq_early_stop,
-                           exact_gram=not qc.rpiq_use_global_hessian,
-                           symmetric=qc.symmetric, impl=qc.rpiq_impl)
-        jax.block_until_ready(res2.w_q)
-    t2 = time.perf_counter()
-    report.seconds_stage2 += t2 - t1
-    report.linears.append(LinearRecord(
-        m.name, shape, float(res1.err), _gamma_list(np.asarray(
-            res2.loss_history)), float(res2.proj_loss),
-        int(res2.iters_run), "rpiq"))
-    return MemberResult(m.name, res2.w_q, grid)
-
-
 def _execute_fallback(qc: QuantConfig, m: PlanMember, report: QuantReport,
                       deferred: Optional[List[Callable[[], None]]] = None
                       ) -> MemberResult:
@@ -776,48 +700,34 @@ def _execute_fallback(qc: QuantConfig, m: PlanMember, report: QuantReport,
 
 
 def execute_plan(qc: QuantConfig, plan: QuantPlan, report: QuantReport,
-                 rpiq_enabled: bool = True,
-                 batched: Optional[bool] = None,
-                 mesh=None, sync: bool = True,
+                 rpiq_enabled: bool = True, mesh=None, sync: bool = True,
                  deferred: Optional[List[Callable[[], None]]] = None
                  ) -> Dict[str, MemberResult]:
-    """Run every group + fallback; returns {member name → MemberResult}.
-
-    ``batched=None`` reads ``qc.batched_executor``; ``False`` forces the
-    legacy per-linear dispatch (parity tests, table4 baseline).
+    """Run every group, then every fallback; returns {member name →
+    MemberResult}.
 
     ``mesh`` (a ``(data, model)`` or ``(data, model, expert)``
     :class:`jax.sharding.Mesh`) turns on sharded group execution: every
-    batched group whose lane count / Cout pass the divisibility guards
-    runs mesh-wide (DESIGN.md §2.6); groups made entirely of stacked
-    expert slabs additionally offer their lane axis to the ``expert``
-    mesh axis (expert parallelism — per-expert Hessians already live
-    with their expert, so the placement adds no collectives). The rest —
-    and the whole plan when ``mesh`` is None or ``batched`` is False —
-    keep the single-device paths.
+    group whose lane count / Cout pass the divisibility guards runs
+    mesh-wide (DESIGN.md §2.6); groups made entirely of stacked expert
+    slabs additionally offer their lane axis to the ``expert`` mesh axis
+    (expert parallelism — per-expert Hessians already live with their
+    expert, so the placement adds no collectives). The rest — and the
+    whole plan when ``mesh`` is None — keep the single-device path.
 
     ``sync=False`` + ``deferred`` is the overlap schedule's contract
-    (core/stream.py): batched stage dispatches stay async and the
-    report-record closures (which synchronize via ``np.asarray``) queue
-    into ``deferred`` for the caller's report boundary. The legacy
-    per-linear path stays per-stage synchronized regardless — it exists
-    as the timing baseline.
+    (core/stream.py): stage dispatches stay async and the report-record
+    closures (which synchronize via ``np.asarray``) queue into
+    ``deferred`` for the caller's report boundary.
     """
-    if batched is None:
-        batched = qc.batched_executor
     out: Dict[str, MemberResult] = {}
     for group in plan.groups:
-        if batched:
-            gshard = quant_group_sharding(
-                mesh, sum(m.lanes for m in group.members), group.key[0],
-                expert_stacked=all(m.stacked for m in group.members))
-            results = _execute_group_batched(qc, group, report, rpiq_enabled,
-                                             gshard, sync=sync,
-                                             deferred=deferred)
-        else:
-            results = [_execute_member_singleton(qc, m, report, rpiq_enabled)
-                       for m in group.members]
-        for r in results:
+        gshard = quant_group_sharding(
+            mesh, sum(m.lanes for m in group.members), group.key[0],
+            expert_stacked=all(m.stacked for m in group.members))
+        for r in _execute_group_batched(qc, group, report, rpiq_enabled,
+                                        gshard, sync=sync,
+                                        deferred=deferred):
             out[r.name] = r
     for m in plan.fallbacks:
         r = _execute_fallback(qc, m, report, deferred=deferred)

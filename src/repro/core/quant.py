@@ -35,7 +35,7 @@ class QuantizedTensor:
     Registered pytree with static metadata aux data, so jit / eval_shape /
     device_put treat (packed, scales, zeros) as array leaves while
     (shape, bits, group_size) stay Python ints — required for the jit'd
-    quantized serve path and the dry-run's ShapeDtypeStruct lowering.
+    quantized serve path and ShapeDtypeStruct lowering.
     """
 
     def __init__(self, packed, scales, zeros, shape: Tuple[int, int],

@@ -49,13 +49,13 @@ residual stream changes, e.g. encoder → decoder) — and
     for that step — when the next step's signature marks the repair
     unsound (``LayerStep.repair_sound=False``; a test seam now that MoE
     repairs at the plan level), when the next item is a
-    :class:`StreamSwitch` fence, when the steps read different stream
-    slots, or when capture runs eagerly (``quant.jit_capture=false``).
+    :class:`StreamSwitch` fence, or when the steps read different stream
+    slots.
 
 Per-run counters land in ``report.pipeline_stats`` — the
 ``serial_fallbacks`` total is split into per-reason counters
-(``fallback_fence`` / ``fallback_cross_slot`` / ``fallback_eager_capture``
-/ ``fallback_repair_unsound`` / ``fallback_flip_budget``) and the MoE
+(``fallback_fence`` / ``fallback_cross_slot`` /
+``fallback_repair_unsound`` / ``fallback_flip_budget``) and the MoE
 flip-repair keeps its own ledger (``moe_spec_layers``,
 ``moe_plan_reuses``, ``moe_flip_repairs``, ``moe_flipped_assignments`` /
 ``moe_assignments``, ``moe_dropped_tokens``) — and the per-step wall
@@ -260,7 +260,7 @@ def _restore_report(report: QuantReport, state: Dict,
 
 
 def run_walker(cfg: Config, walker: LayerWalker, report: QuantReport,
-               fwd_cache: Optional[Dict] = None, mesh=None,
+               fwd_cache: Dict, mesh=None,
                verbose: bool = False) -> Dict:
     """Drain the walker under ``cfg.quant.pipeline``; returns the
     finalized (quantized) param tree.
@@ -276,11 +276,10 @@ def run_walker(cfg: Config, walker: LayerWalker, report: QuantReport,
         raise ValueError(
             f"quant.pipeline must be one of {PIPELINE_MODES}, got {mode!r}")
     overlap = mode == "overlap"
-    use_spec = overlap and qc.jit_capture and fwd_cache is not None
     stats = {"mode": mode, "steps": 0, "spec_captures": 0, "repairs": 0,
              "serial_fallbacks": 0, "fallback_fence": 0,
-             "fallback_cross_slot": 0, "fallback_eager_capture": 0,
-             "fallback_repair_unsound": 0, "fallback_flip_budget": 0,
+             "fallback_cross_slot": 0, "fallback_repair_unsound": 0,
+             "fallback_flip_budget": 0,
              "moe_spec_layers": 0, "moe_plan_reuses": 0,
              "moe_flip_repairs": 0, "moe_flipped_assignments": 0,
              "moe_assignments": 0, "moe_dropped_tokens": 0}
@@ -348,7 +347,7 @@ def run_walker(cfg: Config, walker: LayerWalker, report: QuantReport,
 
     try:
         _run_items(cfg, walker, report, fwd_cache, mesh, verbose, qc,
-                   overlap, use_spec, stats, items, start_idx, ckpt, _save,
+                   overlap, stats, items, start_idx, ckpt, _save,
                    stored_snap)
     finally:
         # join any in-flight async write before propagating — an orphaned
@@ -361,7 +360,7 @@ def run_walker(cfg: Config, walker: LayerWalker, report: QuantReport,
 
 
 def _run_items(cfg, walker, report, fwd_cache, mesh, verbose, qc, overlap,
-               use_spec, stats, items, start_idx, ckpt, save_fn,
+               stats, items, start_idx, ckpt, save_fn,
                stored_snap):
     from repro.core import pipeline as qpipe   # circular-at-import only
 
@@ -393,8 +392,6 @@ def _run_items(cfg, walker, report, fwd_cache, mesh, verbose, qc, overlap,
             if overlap and nxt is not None:
                 if isinstance(nxt, StreamSwitch):
                     spec_block = "fence"
-                elif not use_spec:
-                    spec_block = "eager_capture"
                 elif nxt.hs_slot != item.hs_slot:
                     spec_block = "cross_slot"
                 elif not _repair_sound(qpipe, nxt):
@@ -438,7 +435,7 @@ def _run_items(cfg, walker, report, fwd_cache, mesh, verbose, qc, overlap,
             # 6. propagate quantized activations
             with spans.span(spans.PROPAGATE):
                 walker.streams[item.hs_slot] = qpipe.propagate_layer(
-                    cfg, item, new_params, hs, fwd_cache)
+                    item, new_params, hs, fwd_cache)
             item.store(new_params)
             # 7. report boundary: materialize the deferred executor records
             # and take the per-layer-step wall clock — the only sync in

@@ -12,14 +12,12 @@ schedule-level GPipe:
     processes microbatch ``t − s`` (bubble fraction ``(S−1)/(T+S−1)``);
   - boundary activations move stage→stage+1 with ``lax.ppermute``.
 
-The dry-run proves this lowers and partitions on the (pod, data, model)
-mesh; tests/test_pipeline_parallel.py checks numeric equivalence of the
+tests/test_pipeline_parallel.py checks numeric equivalence of the
 2-stage pipeline against the plain stacked forward on a CPU mesh.
 
 This is the explicit-collective path; the default train config uses GSPMD
 (DP×TP×FSDP) which XLA schedules with overlap. PP is the beyond-paper
-option for DCN-limited multi-pod scaling (EXPERIMENTS.md §Perf discusses
-when each wins).
+option for DCN-limited multi-pod scaling.
 """
 from __future__ import annotations
 

@@ -20,8 +20,7 @@ Strategy (DESIGN.md §2):
 
 Every rule is guarded by divisibility: a dim that doesn't divide by the
 mesh axis size stays unsharded (e.g. whisper's 51866 vocab, minicpm's 36
-heads). This keeps every (arch × mesh) cell lowerable; the roofline then
-shows what the fallback costs.
+heads). This keeps every (arch × mesh) cell lowerable.
 
 ``shard_hint(x, kind)`` is a no-op unless a :class:`Rules` context is
 active, so model code never depends on a mesh.
@@ -31,14 +30,13 @@ from __future__ import annotations
 import contextlib
 import re
 import threading
-from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.config import Config, ParallelConfig
+from repro.config import ParallelConfig
 
 _STATE = threading.local()
 
@@ -53,7 +51,6 @@ class Rules:
     dp_axes: Tuple[str, ...]            # ("pod","data") or ("data",)
     tp_axis: Optional[str] = "model"
     sp: bool = False                    # shard seq dim of residual over TP
-    ep_local_dispatch: bool = True      # shard_map MoE routing (§Perf B)
 
     def axis_size(self, name: Optional[str]) -> int:
         if name is None:
@@ -250,61 +247,6 @@ def param_shardings(params: Any, rules: Rules, fsdp: bool = True) -> Any:
         is_leaf=lambda x: isinstance(x, P))
 
 
-def cache_pspecs(caches: Any, rules: Rules) -> Any:
-    """KV/state caches: batch over DP, kv-heads over TP when divisible.
-
-    Layouts (with leading segment-stack axes of ndim-4/5):
-      k/v:   (..., B, S, KV, hd) → (None.., dp, None, tp, None)
-      ckv:   (..., B, S, rank)   → (None.., dp, None, None)
-      conv:  (..., B, K-1, C)    → (None.., dp, None, tp)
-      h:     (..., B, W[, n])    → (None.., dp, tp[, None])
-    """
-    dp = tuple(rules.dp_axes) if rules.dp_axes else None
-
-    def assign(path, leaf):
-        s = _path_str(path)
-        shape = tuple(leaf.shape)
-        nd = len(shape)
-        ent: List = [None] * nd
-        if re.search(r"(^|/)(k|v)$", s) and nd >= 4:
-            b, sq, kv, hd = shape[-4:]
-            ent[-4] = _guard(dp, b, rules)
-            ent[-2] = _guard(rules.tp_axis, kv, rules)
-            if ent[-2] is None:
-                # kv heads don't divide TP (minicpm 36, whisper 20, MQA 1):
-                # shard the *sequence* dim instead — flash-decoding layout;
-                # softmax over the sharded axis costs one small all-reduce
-                # but cache reads drop 1/|tp| per chip (§Perf cell A it.2)
-                ent[-3] = _guard(rules.tp_axis, sq, rules)
-        elif re.search(r"(ckv|krope)$", s) and nd >= 3:
-            ent[-3] = _guard(dp, shape[-3], rules)
-        elif re.search(r"conv$", s) and nd >= 3:
-            ent[-3] = _guard(dp, shape[-3], rules)
-            ent[-1] = _guard(rules.tp_axis, shape[-1], rules)
-        elif re.search(r"(^|/)h$", s) and nd >= 2:
-            hdim = -2 if nd >= 3 and s.endswith("h") and shape[-1] <= 64 \
-                else -1
-            # mamba h: (B, d_inner, n); rglru h: (B, W)
-            if nd >= 3:
-                ent[-3] = _guard(dp, shape[-3], rules)
-                ent[-2] = _guard(rules.tp_axis, shape[-2], rules)
-            else:
-                ent[-2] = _guard(dp, shape[-2], rules)
-                ent[-1] = _guard(rules.tp_axis, shape[-1], rules)
-        elif nd >= 1:
-            ent[0] = None
-        return P(*ent)
-
-    return jax.tree_util.tree_map_with_path(assign, caches)
-
-
-def cache_shardings(caches: Any, rules: Rules) -> Any:
-    specs = cache_pspecs(caches, rules)
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(rules.mesh, s), specs,
-        is_leaf=lambda x: isinstance(x, P))
-
-
 def batch_shardings(batch: Any, rules: Rules) -> Any:
     dp = tuple(rules.dp_axes) if rules.dp_axes else None
 
@@ -316,68 +258,6 @@ def batch_shardings(batch: Any, rules: Rules) -> Any:
         return NamedSharding(rules.mesh, P(*ent))
 
     return jax.tree_util.tree_map(assign, batch)
-
-
-def train_state_shardings(state: Any, rules: Rules,
-                          fsdp: bool = True) -> Any:
-    """NamedShardings for a TrainState (params + Adam moments + step).
-
-    f32 moments mirror the param specs (same shapes). int8 moments
-    (``Quantized8``: (n_blocks, 128) payload + (n_blocks,) scale) shard the
-    block dim over data when it divides.
-    """
-    from repro.training.train_step import TrainState
-    from repro.training.optimizer import AdamWState, Quantized8
-
-    pspecs = param_pspecs(state.params, rules, fsdp)
-
-    def moment_spec(path, leaf):
-        shape = tuple(leaf.shape)
-        s = _path_str(path)
-        # Quantized8 children show up as trailing /q and /scale (NamedTuple)
-        if s.endswith("/q") or s.endswith("/scale") or len(shape) <= 1:
-            ent: List = [None] * len(shape)
-            if shape and fsdp:
-                ent[0] = _guard("data", shape[0], rules)
-            return P(*ent)
-        return None  # handled by mirroring below
-
-    def mirror(ps, leaf):
-        if isinstance(ps, P) and len(ps) == len(leaf.shape):
-            return ps
-        return P(*([None] * len(leaf.shape)))
-
-    is_q8 = lambda x: isinstance(x, Quantized8)
-    has_q8 = any(is_q8(l) for l in jax.tree_util.tree_leaves(
-        state.opt.m, is_leaf=is_q8))
-
-    if has_q8:
-        def q8_specs(tree):
-            return jax.tree_util.tree_map_with_path(
-                lambda p, l: moment_spec(p, l) or P(
-                    *([None] * len(l.shape))), tree)
-        m_specs = q8_specs(state.opt.m)
-        v_specs = q8_specs(state.opt.v)
-    else:
-        is_p = lambda x: isinstance(x, P)
-        m_specs = jax.tree_util.tree_map(mirror, pspecs, state.opt.m,
-                                         is_leaf=is_p)
-        v_specs = jax.tree_util.tree_map(mirror, pspecs, state.opt.v,
-                                         is_leaf=is_p)
-
-    specs = TrainState(pspecs,
-                       AdamWState(P(), m_specs, v_specs),
-                       P())
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(rules.mesh, s), specs,
-        is_leaf=lambda x: isinstance(x, P))
-
-
-def sds_with_shardings(tree: Any, shardings: Any) -> Any:
-    """ShapeDtypeStructs carrying NamedShardings (dry-run inputs)."""
-    return jax.tree_util.tree_map(
-        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
-        tree, shardings)
 
 
 # ---------------------------------------------------------------------------
@@ -499,6 +379,4 @@ def make_rules(mesh: Mesh, parallel: Optional[ParallelConfig] = None
     dp = tuple(a for a in ("pod", "data") if a in names)
     tp = "model" if "model" in names else None
     sp = bool(parallel.sp) if parallel is not None else False
-    epl = bool(parallel.ep_local_dispatch) if parallel is not None else True
-    return Rules(mesh=mesh, dp_axes=dp, tp_axis=tp, sp=sp,
-                 ep_local_dispatch=epl)
+    return Rules(mesh=mesh, dp_axes=dp, tp_axis=tp, sp=sp)

@@ -2,8 +2,6 @@
 
   - hessian_accum  — H = X^T X calibration Gram accumulation (paper eq. 9)
   - w4a16_matmul   — int4-grouped dequant matmul (quantized serving path)
-  - quant_pack     — fused quantize-to-grid + nibble pack (stage-2 projection
-                     and deployment packing)
   - gptq_block     — the stage-1 GPTQ lazy-block sweep fused into ONE
                      ``pallas_call``: grid (members, Cout tiles, lazy
                      blocks), the working row tile stays VMEM-resident

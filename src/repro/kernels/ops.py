@@ -3,7 +3,7 @@
 Every op has three implementations:
   - ``*_pallas``  — the TPU kernel (interpret=True on CPU for validation),
   - ``*_ref``     — the pure-jnp oracle in :mod:`repro.kernels.ref`,
-  - an XLA path (== ref) used for dry-run lowering and non-TPU backends.
+  - an XLA path (== ref) used on non-TPU backends.
 
 ``impl`` selects: "auto" (pallas-interpret only when explicitly requested on
 CPU; real Mosaic lowering on TPU), "pallas", "xla". The CPU container always
@@ -27,7 +27,6 @@ from repro.kernels import ref
 from repro.kernels.gptq_block import gptq_block_pallas
 from repro.kernels.rpiq_block import rpiq_block_pallas
 from repro.kernels.hessian_accum import hessian_accum_pallas
-from repro.kernels.quant_pack import quant_pack_pallas
 from repro.kernels.selective_scan import selective_scan_pallas
 from repro.kernels.w4a16_matmul import w4a16_matmul_pallas
 from repro.kernels.kv_attention import int8_kv_attention_pallas
@@ -385,28 +384,6 @@ def int8_kv_attention(q: jax.Array, k_codes: jax.Array, k_scales: jax.Array,
                                  interpret=((not _on_tpu()) if interpret
                                             is None else interpret))
     return y[:, :, :r]
-
-
-# ---------------------------------------------------------------------------
-# quantize-to-grid + pack nibbles
-# ---------------------------------------------------------------------------
-
-def quant_pack(w: jax.Array, scales: jax.Array, zeros: jax.Array, *,
-               group_size: int = 128, impl: str = "auto") -> jax.Array:
-    """w: (n, k) float → (n, k//2) uint8 codes on the (scales, zeros) grid."""
-    if impl == "xla" or (impl == "auto" and not _on_tpu()):
-        return ref.quant_pack_ref(w, scales, zeros, group_size)
-    n, k = w.shape
-    block_n = 256 if n >= 256 else max(8, n)
-    n_pad = _round_up(n, block_n)
-    if n_pad != n:
-        w = jnp.pad(w, ((0, n_pad - n), (0, 0)))
-        scales = jnp.pad(scales, ((0, n_pad - n), (0, 0)), constant_values=1.0)
-        zeros = jnp.pad(zeros, ((0, n_pad - n), (0, 0)))
-    out = quant_pack_pallas(w, scales, zeros, group_size=group_size,
-                            block_n=block_n, block_k=min(512, k),
-                            interpret=not _on_tpu())
-    return out[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -856,8 +833,8 @@ def selective_scan(u, dt, bm, cm, a_log, d_skip, h0, *, impl: str = "auto",
     """Diagonal SSM scan. See kernels/selective_scan.py for shapes.
 
     XLA fallback = chunked associative scan (materializes (B, chunk, d, n)
-    per chunk — the §Perf cell-C baseline); pallas path keeps the state in
-    VMEM (O(B·S·d) HBM traffic).
+    per chunk); pallas path keeps the state in VMEM (O(B·S·d) HBM
+    traffic).
     """
     if impl == "pallas" or (impl == "auto" and _on_tpu()):
         B, S, d = u.shape
@@ -888,6 +865,6 @@ def selective_scan(u, dt, bm, cm, a_log, d_skip, h0, *, impl: str = "auto",
 
 __all__ = ["hessian_accum", "w4a16_matmul", "w4a16_default_impl",
            "int8_kv_attention", "kv_attn_default_impl",
-           "quant_pack", "gptq_block", "gptq_block_sharded", "rpiq_block",
+           "gptq_block", "gptq_block_sharded", "rpiq_block",
            "rpiq_block_sharded", "selective_scan", "fallback_stats",
            "reset_fallback_stats"]

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth for the per-kernel allclose tests and the XLA
-fallback implementations used on non-TPU backends (e.g. the CPU dry-run).
+fallback implementations used on non-TPU backends.
 """
 from __future__ import annotations
 
@@ -236,19 +236,3 @@ def rpiq_block_ref(w_init, w_fp, x_last, hinv_blocks, scales, zeros, *,
         if early_stop and gamma >= hist[t] * (1.0 - 1e-6):
             break
     return (best_w, w, hist, np.float32(best_loss), np.int32(iters))
-
-
-def quant_pack_ref(w: jax.Array, scales: jax.Array, zeros: jax.Array,
-                   group_size: int) -> jax.Array:
-    """Quantize to 4-bit codes on a fixed grid and pack 2 codes/byte.
-
-    w: (n, k); scales/zeros: (n, k//group_size). Returns (n, k//2) uint8.
-    """
-    n, k = w.shape
-    s = jnp.repeat(scales.astype(jnp.float32), group_size, axis=1)
-    z = jnp.repeat(zeros.astype(jnp.float32), group_size, axis=1)
-    q = jnp.clip(jnp.round(w.astype(jnp.float32) / s) + z, 0.0, 15.0)
-    q = q.astype(jnp.uint8)
-    lo = q[:, 0::2]
-    hi = q[:, 1::2]
-    return (lo | (hi << 4)).astype(jnp.uint8)

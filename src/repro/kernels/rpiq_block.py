@@ -40,8 +40,8 @@ This kernel runs EVERY Gauss–Seidel round inside one ``pallas_call``:
 
 Consequences of running rounds unconditionally (documented trade):
   - lanes that early-stop still execute their remaining ≤ t_max−1 rounds
-    (dead weight bounded by the small t_max, default 5; the dispatch-count
-    win dominates — measured in benchmarks/table4_time.py);
+    (dead weight bounded by the small t_max, default 5, against one
+    dispatch for all rounds);
   - the returned ``w_cont`` is the t_max-round iterate, not the stop-round
     iterate, whenever early stop fires before t_max.  ``w_q``,
     ``loss_history``, ``proj_loss`` and ``iters_run`` — everything the

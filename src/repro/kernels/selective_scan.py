@@ -1,8 +1,7 @@
 """Pallas TPU kernel: Mamba-1 selective scan with VMEM-resident state.
 
-The §Perf cell-C hot spot: the pure-JAX associative scan materializes the
-(B, S, d_inner, n) state-expansion tensors a = exp(Δ⊙A) and b = Δ⊙B⊙u in
-HBM (~25× the residual-stream bytes for falcon-mamba prefill_32k —
+The pure-JAX associative scan materializes the (B, S, d_inner, n)
+state-expansion tensors a = exp(Δ⊙A) and b = Δ⊙B⊙u in HBM (~25× the residual-stream bytes for falcon-mamba prefill_32k —
 measured). The CUDA reference (selective_scan_cuda) keeps h in shared
 memory; the TPU-native formulation here:
 

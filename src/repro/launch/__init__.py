@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/quantize."""
+"""Launchers: meshes, train/serve/quantize entry points."""

@@ -1,10 +1,10 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Functions (not module-level constants) so importing never touches jax
-device state. Single pod: 16×16 = 256 chips (v5e pod), axes (data, model).
-Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model) — the ``pod`` axis
-crosses DCN; sharding rules keep per-layer traffic off it (DP gradient
-reduction and optional GPipe stages are the only pod-axis collectives).
+device state. Axes are (data, model), or (pod, data, model) with a ``pod``
+axis that crosses DCN; sharding rules keep per-layer traffic off it (DP
+gradient reduction and optional GPipe stages are the only pod-axis
+collectives).
 
 ``make_quant_mesh`` resolves the ``quant.mesh`` knob into the
 ``(data, model)`` — or, with an expert-parallel axis, ``(data, model,
@@ -25,12 +25,6 @@ def _mesh(shape, axes) -> Mesh:
     GSPMD-style (the compiler propagates shardings), which Explicit axes —
     the default since JAX 0.9 — reject at the first resharding op."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,

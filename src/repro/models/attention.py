@@ -28,7 +28,6 @@ stacking, so the slot API in models/transformer.py works unchanged —
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -63,7 +62,7 @@ def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 def _attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_positions: jax.Array, kv_positions: jax.Array,
                     *, causal: bool, window: int, softcap: float,
-                    chunk: int = 512, opt: bool = True) -> jax.Array:
+                    chunk: int = 512) -> jax.Array:
     """Online-softmax attention.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, H, hd) (kv already head-repeated).
@@ -88,17 +87,12 @@ def _attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def one_chunk(carry, xs):
         qi, pi = xs                                  # (B, c, H, hd), (B, c)
-        if opt:
-            # matmuls stay in the compute dtype with f32 accumulation — an
-            # .astype(f32) on kT/vT makes XLA hoist full-precision copies
-            # of K/V out of the chunk loop (measured 2× attention bytes)
-            s = jnp.einsum("bchd,bhdk->bhck",
-                           (qi.astype(jnp.float32) * scale).astype(qi.dtype),
-                           kT, preferred_element_type=jnp.float32)
-        else:                       # naive baseline (§Perf before-state)
-            s = jnp.einsum("bchd,bhdk->bhck",
-                           qi.astype(jnp.float32) * scale,
-                           kT.astype(jnp.float32))
+        # matmuls stay in the compute dtype with f32 accumulation — an
+        # .astype(f32) on kT/vT makes XLA hoist full-precision copies of
+        # K/V out of the chunk loop
+        s = jnp.einsum("bchd,bhdk->bhck",
+                       (qi.astype(jnp.float32) * scale).astype(qi.dtype),
+                       kT, preferred_element_type=jnp.float32)
         s = _softcap(s, softcap)
         mask = jnp.ones((b, 1, chunk, sk), bool)
         dq = pi[:, None, :, None]                    # (B,1,c,1)
@@ -112,11 +106,8 @@ def _attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         p = jax.nn.softmax(s, axis=-1)
         # fully-masked rows (padding) give uniform p; output is garbage but
         # sliced away below.
-        if opt:
-            o = jnp.einsum("bhck,bhkd->bchd", p.astype(vT.dtype), vT,
-                           preferred_element_type=jnp.float32)
-        else:
-            o = jnp.einsum("bhck,bhkd->bchd", p, vT.astype(jnp.float32))
+        o = jnp.einsum("bhck,bhkd->bchd", p.astype(vT.dtype), vT,
+                       preferred_element_type=jnp.float32)
         return carry, o.astype(q.dtype)
 
     _, out = jax.lax.scan(one_chunk, (), (qc, pc))
@@ -160,8 +151,7 @@ def attention_forward(cfg: ModelConfig, p: Dict, x: jax.Array,
     n_rep = cfg.num_heads // cfg.num_kv_heads
     o = _attend_chunked(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
                         positions, positions, causal=causal, window=window,
-                        softcap=cfg.attn_logits_softcap,
-                        opt=cfg.opt_attention)
+                        softcap=cfg.attn_logits_softcap)
     b, s, _, _ = o.shape
     return dense(p["o"], o.reshape(b, s, -1), f"{name}.o")
 
@@ -220,8 +210,7 @@ def attention_prefill(cfg: ModelConfig, p: Dict, x: jax.Array,
     if start is None:
         o = _attend_chunked(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
                             positions, positions, causal=True, window=window,
-                            softcap=cfg.attn_logits_softcap,
-                            opt=cfg.opt_attention)
+                            softcap=cfg.attn_logits_softcap)
     else:
         # continuation: history keys come from the cache as written by the
         # PREVIOUS chunks (read before this chunk's write — a ring cache
@@ -245,8 +234,7 @@ def attention_prefill(cfg: ModelConfig, p: Dict, x: jax.Array,
                                  repeat_kv(v, n_rep)], axis=1)
         kv_pos = jnp.concatenate([old_kpos, positions], axis=1)
         o = _attend_chunked(q, k_all, v_all, positions, kv_pos, causal=True,
-                            window=window, softcap=cfg.attn_logits_softcap,
-                            opt=cfg.opt_attention)
+                            window=window, softcap=cfg.attn_logits_softcap)
     y = dense(p["o"], o.reshape(b, s, -1), f"{name}.o")
 
     w_cache = cache["k"].shape[1]
@@ -392,13 +380,12 @@ def attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array,
                                    kv_block=blk,
                                    softcap=cfg.attn_logits_softcap)
         o = o.astype(x.dtype)
-    elif cfg.opt_attention:
+    else:
         # grouped-query attention against the cache WITHOUT materializing an
         # f32 copy of the cache or the head-repeated expansion: the einsum
-        # contracts bf16 cache entries directly with f32 accumulation. (The
-        # naive repeat_kv(...).astype(f32) form makes XLA hoist a full f32
-        # copy of the entire stacked cache out of the layer scan — ~2.5× the
-        # whole decode memory term on minicpm; measured in §Perf.)
+        # contracts bf16 cache entries directly with f32 accumulation (a
+        # repeat_kv(...).astype(f32) form makes XLA hoist a full f32 copy
+        # of the entire stacked cache out of the layer scan).
         n_rep = h // kv
         qg = (q[:, 0] * hd ** -0.5).reshape(b, kv, n_rep, hd)
         s = jnp.einsum("bgrd,bsgd->bgrs", qg.astype(ck.dtype), ck,
@@ -408,16 +395,6 @@ def attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array,
         pw = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bgrs,bsgd->bgrd", pw.astype(cv.dtype), cv,
                        preferred_element_type=jnp.float32).astype(x.dtype)
-    else:                               # naive baseline (§Perf before-state)
-        n_rep = h // kv
-        kk = repeat_kv(ck, n_rep).astype(jnp.float32)            # (B,S,H,hd)
-        vv = repeat_kv(cv, n_rep).astype(jnp.float32)
-        s = jnp.einsum("bhd,bshd->bhs",
-                       q[:, 0].astype(jnp.float32) * hd ** -0.5, kk)
-        s = _softcap(s, cfg.attn_logits_softcap)
-        s = jnp.where(kpos[:, None, :] >= 0, s, NEG_INF)
-        pw = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhs,bshd->bhd", pw, vv).astype(x.dtype)
     y = dense(p["o"], o.reshape(b, 1, h * hd), f"{name}.o")
     return y, new_cache
 
@@ -454,7 +431,7 @@ def cross_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     qpos = jnp.zeros((b, s), jnp.int32)
     kpos = jnp.zeros((b, se), jnp.int32)
     o = _attend_chunked(q, k, v, qpos, kpos, causal=False, window=0,
-                        softcap=0.0, opt=cfg.opt_attention)
+                        softcap=0.0)
     return dense(p["o"], o.reshape(b, s, -1), f"{name}.o")
 
 
@@ -517,8 +494,7 @@ def _mla_attend(cfg: ModelConfig, p: Dict, q_nope, q_rope, ckv, k_rope,
     if m.v_head_dim < qk_hd:
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk_hd - m.v_head_dim)))
     o = _attend_chunked(q, k, v, q_positions, kv_positions, causal=causal,
-                        window=0, softcap=cfg.attn_logits_softcap,
-                        opt=cfg.opt_attention)
+                        window=0, softcap=cfg.attn_logits_softcap)
     o = o[..., :m.v_head_dim]
     sq = o.shape[1]
     return dense(p["o"], o.reshape(b, sq, -1), f"{name}.o")

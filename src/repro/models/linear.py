@@ -19,7 +19,7 @@ runs compiled without framework hooks.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -31,17 +31,13 @@ _ACTIVE_TAPS: List["Tap"] = []
 
 
 class Tap:
-    """Context manager that observes inputs of named dense layers.
+    """Context manager that observes inputs of named dense layers: each
+    time a matching dense layer runs, its input is appended to
+    ``records[name]``."""
 
-    ``on_record(name, x)`` is called with the *eager* input array each time
-    a matching dense layer runs. Default behaviour appends to ``records``.
-    """
-
-    def __init__(self, on_record: Optional[Callable[[str, jax.Array], None]]
-                 = None, prefix: str = "", collect_tracers: bool = False):
+    def __init__(self, prefix: str = "", collect_tracers: bool = False):
         self.prefix = prefix
         self.records: Dict[str, List[jax.Array]] = {}
-        self._on_record = on_record
         self._collect_tracers = collect_tracers
 
     def __enter__(self) -> "Tap":
@@ -63,10 +59,7 @@ class Tap:
             raise RuntimeError(
                 f"Tap saw a tracer for {name!r}: calibration forwards must "
                 "run eagerly (outside jit)")
-        if self._on_record is not None:
-            self._on_record(name, x)
-        else:
-            self.records.setdefault(name, []).append(x)
+        self.records.setdefault(name, []).append(x)
 
 
 def dense(p: Dict, x: jax.Array, name: str = "") -> jax.Array:

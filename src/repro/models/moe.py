@@ -10,8 +10,7 @@ Design notes (DESIGN.md §2):
     Everything is jit-safe and O(T·K log T·K) with no (T, E) one-hots.
   - expert weights are stacked (E, ...) and sharded over the ``model`` mesh
     axis (EP); the buffer's expert axis is sharded likewise, so XLA lowers
-    the scatter/gather into an all-to-all pair — the MoE collective the
-    roofline tracks.
+    the scatter/gather into an all-to-all pair.
   - tokens over capacity are *dropped* (contribute nothing; the residual
     stream passes them through) — standard capacity-factor semantics.
   - router runs in float32; aux load-balance loss returned for training.
@@ -25,7 +24,7 @@ core/pipeline.py).
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -235,14 +234,12 @@ def moe_ffn(cfg: ModelConfig, p: Dict, x: jax.Array,
     ``shard_map`` (manual over the DP axes, GSPMD-auto over ``model``): the
     argsort/scatter routing then stays **local to each data shard** instead
     of forcing GSPMD to materialize the global (T·K, d) dispatch on every
-    chip (measured 58 replicated full-size gathers/layer on deepseek-v3
-    train_4k — §Perf cell B). Expert einsums still partition over ``model``
-    (EP) inside the auto region.
+    chip. Expert einsums still partition over ``model`` (EP) inside the
+    auto region.
     """
     from repro.distributed.sharding import current_rules
     rules = current_rules()
     if (rules is not None and rules.dp_axes
-            and getattr(rules, "ep_local_dispatch", True)
             and x.shape[0] % rules.dp_size() == 0):
         from jax.sharding import PartitionSpec as P
         dp = tuple(rules.dp_axes)

@@ -9,8 +9,8 @@ with ``lax.scan`` over stacked params → small HLO even for 61-layer models)
 or a run of identical layers. recurrentgemma's (rglru, rglru, local)×12+2
 and deepseek's 3-dense + 58-MoE both segment cleanly.
 
-Param layout (canonical, used by training, serving, dry-run and the
-quantization pipeline):
+Param layout (canonical, used by training, serving and the quantization
+pipeline):
 
   params = {
     "embed": {...}, "final_norm": {...}, "lm_head"?: {...},

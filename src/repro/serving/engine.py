@@ -1,11 +1,10 @@
 """Batched serving engine.
 
-``serve_step`` is the unit the dry-run lowers for decode shapes: one new
-token for every sequence in the batch against a populated cache. The engine
-wraps it in a greedy/temperature generation loop with a ragged-completion
-mask (sequences finish independently; finished lanes keep decoding pad
-tokens but their outputs are frozen — the standard static-shape batch
-pattern).
+``serve_step`` is the decode unit: one new token for every sequence in
+the batch against a populated cache. The engine wraps it in a
+greedy/temperature generation loop with a ragged-completion mask
+(sequences finish independently; finished lanes keep decoding pad tokens
+but their outputs are frozen — the standard static-shape batch pattern).
 
 EOS convention: the eos token itself is never emitted. The step that
 samples eos writes token 0 / logprob 0.0 and marks the lane done, so
@@ -15,10 +14,9 @@ the done mask, not from ``tokens != 0``).
 
 Weights may be full precision or int4-packed (``QuantizedTensor`` leaves,
 produced by core/pipeline.quantize_model) — ``models.linear.dense``
-dispatches per leaf, so the same step function serves both and the dry-run
-can lower the quantized decode path explicitly (the paper's deployment
-claim: §Perf compares both). The quantized matmul backend is selected by
-``serve.w4a16_impl`` (kernels.ops.w4a16_default_impl trace-time context).
+dispatches per leaf, so the same step function serves both. The quantized
+matmul backend is selected by ``serve.w4a16_impl``
+(kernels.ops.w4a16_default_impl trace-time context).
 
 The static-batch loop here is the parity baseline; the continuous-batching
 scheduler lives in serving/scheduler.py (docs/SERVING.md).
@@ -89,7 +87,7 @@ def decode_step_guarded(cfg: Config, params: Any, token: jax.Array,
 
 def serve_step(cfg: Config, params: Any, token: jax.Array, pos: jax.Array,
                caches: Any) -> Tuple[jax.Array, Any]:
-    """One decode step (the dry-run unit). token/pos: (B,)."""
+    """One decode step. token/pos: (B,)."""
     if cfg.model.is_encoder_decoder:
         return T.encdec_decode_step(cfg.model, params, token, pos, caches)
     return T.decode_step(cfg.model, params, token, pos, caches)

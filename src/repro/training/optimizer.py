@@ -3,11 +3,10 @@
 The int8 moments are the Dettmers-style distributed-optimization trick the
 paper's own method echoes (block-wise quantization): each moment tensor is
 flattened into blocks of 128, stored int8 with a per-block f32 absmax
-scale — 4× smaller optimizer state, which is what lets deepseek-v3 train
-inside v5e HBM at 512 chips (EXPERIMENTS.md §Dry-run). Moments are
-dequantized, updated in f32, and requantized every step; the quantization
-noise on m/v is well inside Adam's own noise floor (tested against exact
-AdamW in tests/test_training.py).
+scale — 4× smaller optimizer state. Moments are dequantized, updated in
+f32, and requantized every step; the quantization noise on m/v is well
+inside Adam's own noise floor (tested against exact AdamW in
+tests/test_training.py).
 """
 from __future__ import annotations
 

@@ -6,7 +6,7 @@ Invoked by test_distributed.py as::
         python tests/_distributed_checks.py <check-name>
 
 Keeping these out of the main pytest process means unit tests still see the
-single real CPU device (required by the dry-run contract).
+single real CPU device.
 """
 import os
 import sys

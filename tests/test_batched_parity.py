@@ -3,14 +3,16 @@
 The whole point of the QuantPlan refactor is that grouping same-shape
 linears into one vmapped dispatch changes NOTHING numerically: every test
 here pins the batched entry points against mapping the single-linear
-functions over the stack, including the MoE starved-expert RTN mask and
-the full pipeline on an MoE model.
+functions over the stack (the plan-level reference is the test-only
+per-linear executor in tests/_perlinear.py), including the MoE
+starved-expert RTN mask and the full pipeline on an MoE model.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _perlinear import execute_plan_per_linear
 from repro.configs import get_config
 from repro.core import hessian as hess
 from repro.core import plan as qplan
@@ -220,15 +222,16 @@ class TestPlanExecution:
         assert plan.n_members == len(ms)
 
     def test_batched_matches_singleton_execution(self, stack_problem):
-        """Same plan through both executors → same weights, grids, modes —
-        including the starved-member RTN mask."""
+        """Same plan through the executor and the per-linear reference →
+        same weights, grids, modes — including the starved-member RTN
+        mask."""
         p = stack_problem
         qc = self._qc()
         rep_b, rep_s = qplan.QuantReport(), qplan.QuantReport()
         plan_b = qplan.build_plan(qc, self._members(p, starve=(2,)))
         plan_s = qplan.build_plan(qc, self._members(p, starve=(2,)))
-        out_b = qplan.execute_plan(qc, plan_b, rep_b, batched=True)
-        out_s = qplan.execute_plan(qc, plan_s, rep_s, batched=False)
+        out_b = qplan.execute_plan(qc, plan_b, rep_b)
+        out_s = execute_plan_per_linear(qc, plan_s, rep_s)
         assert out_b.keys() == out_s.keys()
         for name in out_b:
             np.testing.assert_allclose(np.asarray(out_b[name].w_q),
@@ -246,7 +249,7 @@ class TestPlanExecution:
     def test_zero_token_starved_lane(self, stack_problem):
         """A starved member with ZERO routed tokens (H = 0, x_count = 0)
         must not poison the group: outputs stay finite, modes match the
-        singleton path, and the lane's early stop fires instead of
+        per-linear reference, and the lane's early stop fires instead of
         pinning the vmapped while_loop at t_max."""
         p = stack_problem
         qc = self._qc()
@@ -256,11 +259,11 @@ class TestPlanExecution:
             hess.init_hessian(in_dim), jnp.zeros_like(p["X"][0]),
             x_count=jnp.zeros((), jnp.int32), starved=True)
         outs = {}
-        for batched in (True, False):
+        for batched, execute in ((True, qplan.execute_plan),
+                                 (False, execute_plan_per_linear)):
             rep = qplan.QuantReport()
             plan = qplan.build_plan(qc, self._members(p) + [dead])
-            outs[batched] = qplan.execute_plan(qc, plan, rep,
-                                               batched=batched)
+            outs[batched] = execute(qc, plan, rep)
             assert {l.name: l.mode for l in rep.linears}["dead"] \
                 == "rtn-fallback"
         for name in outs[True]:
@@ -271,7 +274,7 @@ class TestPlanExecution:
 
     def test_fallback_starved_group_aligned_keeps_grid(self, stack_problem):
         """group_size-aligned but blocksize-unaligned starved expert still
-        gets per-group RTN (legacy semantics), with a stored grid."""
+        gets per-group RTN, with a stored grid."""
         qc = self._qc()                  # group 32, blocksize 64
         w = jax.random.normal(jax.random.PRNGKey(3), (8, 96)) * 0.1
         x = jnp.zeros((16, 96))
@@ -304,9 +307,9 @@ class TestPlanExecution:
             for i in range(S)]
         rep_a, rep_b = qplan.QuantReport(), qplan.QuantReport()
         out_a = qplan.execute_plan(qc, qplan.build_plan(qc, [stacked]),
-                                   rep_a, batched=True)
+                                   rep_a)
         out_b = qplan.execute_plan(qc, qplan.build_plan(qc, singles),
-                                   rep_b, batched=True)
+                                   rep_b)
         assert out_a["slab"].w_q.shape == (S, *p["W"].shape[1:])
         for i in range(S):
             np.testing.assert_allclose(
@@ -314,10 +317,10 @@ class TestPlanExecution:
                 np.asarray(out_b[f"slab[{i}]"].w_q), atol=2e-5)
         assert {l.name: l.mode for l in rep_a.linears} \
             == {l.name: l.mode for l in rep_b.linears}
-        # singleton executor over the stacked member agrees too
+        # the per-linear reference over the stacked member agrees too
         rep_c = qplan.QuantReport()
-        out_c = qplan.execute_plan(qc, qplan.build_plan(qc, [stacked]),
-                                   rep_c, batched=False)
+        out_c = execute_plan_per_linear(
+            qc, qplan.build_plan(qc, [stacked]), rep_c)
         np.testing.assert_allclose(np.asarray(out_a["slab"].w_q),
                                    np.asarray(out_c["slab"].w_q), atol=2e-5)
 
@@ -350,7 +353,7 @@ class TestPlanExecution:
         qc.rpiq_iters = 0
         rep = qplan.QuantReport()
         plan = qplan.build_plan(qc, self._members(p))
-        out = qplan.execute_plan(qc, plan, rep, batched=True)
+        out = qplan.execute_plan(qc, plan, rep)
         assert all(l.mode == "gptq" for l in rep.linears)
         assert len(out) == p["B"]
 
@@ -371,7 +374,7 @@ class TestExecutorCache:
                                    p["X"][i], x_count=None)
                   for i in range(p["B"])]
             plan = qplan.build_plan(qc, ms)
-            qplan.execute_plan(qc, plan, qplan.QuantReport(), batched=True)
+            qplan.execute_plan(qc, plan, qplan.QuantReport())
 
         run_once("layer0.")          # cold: one miss per stage
         s1 = qplan.executor_cache_stats()
@@ -389,32 +392,33 @@ class TestExecutorCache:
         m = qplan.PlanMember("a", p["W"][0], p["sts"][0], p["X"][0],
                              x_count=None)
         qplan.execute_plan(qc, qplan.build_plan(qc, [m]),
-                           qplan.QuantReport(), batched=True)
+                           qplan.QuantReport())
         # different group_size → different signature → fresh entries
         qc2 = QuantConfig(group_size=64, blocksize=64, rpiq_iters=2,
                           rpiq_alpha=0.25)
         m2 = qplan.PlanMember("b", p["W"][0], p["sts"][0], p["X"][0],
                               x_count=None)
         qplan.execute_plan(qc2, qplan.build_plan(qc2, [m2]),
-                           qplan.QuantReport(), batched=True)
+                           qplan.QuantReport())
         st = qplan.executor_cache_stats()
         assert st["misses"] == 4 and st["hits"] == 0
 
 
 @pytest.mark.slow
 class TestPipelineParity:
-    def test_moe_pipeline_batched_matches_perlinear(self):
+    def test_moe_pipeline_batched_matches_perlinear(self, monkeypatch):
         """Quantized MoE params (8 experts) identical on a fixed seed
-        whether groups run batched or per-linear."""
+        whether groups run through the executor or the per-linear
+        reference."""
         from repro.core.pipeline import quantize_model
         from repro.data import MarkovLM, calibration_batches
 
         from repro.models import transformer as T
 
         outs, reports = [], []
-        for batched in (False, True):
+        for execute in (execute_plan_per_linear, qplan.execute_plan):
+            monkeypatch.setattr(qplan, "execute_plan", execute)
             cfg = get_config("olmoe-1b-7b", smoke=True)
-            cfg.quant.batched_executor = batched
             mc = cfg.model
             params = T.init_params(mc, jax.random.PRNGKey(0))
             calib = calibration_batches(MarkovLM(mc.vocab_size, seed=1),

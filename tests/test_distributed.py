@@ -1,5 +1,5 @@
 """Multi-device behaviour, run in subprocesses so the main pytest process
-keeps the single real CPU device (dry-run contract)."""
+keeps the single real CPU device."""
 import os
 import subprocess
 import sys

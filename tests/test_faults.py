@@ -502,17 +502,13 @@ class TestKernelFallbackAccounting:
         from repro.core import plan as qplan
         from repro.kernels import ref as kref
         # fake a zero-VMEM TPU so the budget-gated executors downgrade; the
-        # un-gated pallas entry points (hessian accum, pack) are pinned to
-        # their reference paths — they have no budget ladder to exercise
-        # and would otherwise try a real Mosaic compile on this host
+        # un-gated hessian accumulation is pinned to its reference path —
+        # it has no budget ladder to exercise and would otherwise try a
+        # real Mosaic compile on this host
         monkeypatch.setattr(kops, "_on_tpu", lambda: True)
         monkeypatch.setattr(kops, "_VMEM_BUDGET_BYTES", 0)
         monkeypatch.setattr(kops, "hessian_accum",
                             lambda x, **k: kref.hessian_accum_ref(x))
-        monkeypatch.setattr(
-            kops, "quant_pack",
-            lambda w, s, z, **k: kref.quant_pack_ref(
-                w, s, z, k.get("group_size", 128)))
         # trace-time decisions only fire on fresh compiles: drop executors
         # cached by earlier tests in this process
         qplan.clear_executor_cache()

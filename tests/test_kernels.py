@@ -10,12 +10,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, st
 
 from repro.core.quant import pack_quantized
 from repro.kernels import ops, ref
 from repro.kernels.hessian_accum import hessian_accum_pallas
-from repro.kernels.quant_pack import quant_pack_pallas
 from repro.kernels.selective_scan import selective_scan_pallas
 from repro.kernels.w4a16_matmul import w4a16_matmul_pallas
 
@@ -268,29 +266,3 @@ class TestSelectiveScanKernel:
                                    rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2),
                                    rtol=2e-4, atol=2e-4)
-
-
-class TestQuantPackKernel:
-    @pytest.mark.parametrize("n,k,g,bn,bk", [
-        (64, 256, 128, 32, 256), (256, 512, 128, 256, 256),
-        (32, 128, 64, 32, 128),
-    ])
-    def test_shapes(self, n, k, g, bn, bk):
-        w = _rand((n, k), n + k) * 0.2
-        from repro.core.quant import compute_qparams
-        qp = compute_qparams(w, 4, g)
-        out = quant_pack_pallas(w, qp.scales, qp.zeros, group_size=g,
-                                block_n=bn, block_k=bk, interpret=True)
-        ref_out = ref.quant_pack_ref(w, qp.scales, qp.zeros, g)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
-
-    @given(seed=st.integers(0, 100))
-    @settings(max_examples=10, deadline=None)
-    def test_property_matches_ref(self, seed):
-        w = _rand((32, 128), seed) * (0.1 + seed % 5)
-        from repro.core.quant import compute_qparams
-        qp = compute_qparams(w, 4, 64)
-        out = quant_pack_pallas(w, qp.scales, qp.zeros, group_size=64,
-                                block_n=32, block_k=128, interpret=True)
-        ref_out = ref.quant_pack_ref(w, qp.scales, qp.zeros, 64)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))

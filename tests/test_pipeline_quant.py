@@ -141,38 +141,58 @@ class TestPipeline:
 
 
 class TestJitCapture:
-    """The jitted calibration forward (quant.jit_capture) must match the
-    legacy eager capture and reuse compiled entries across repeated
+    """The jitted calibration forward must capture what an eager run of
+    the same layer sees, and reuse compiled entries across repeated
     layers."""
 
     def test_jit_capture_matches_eager(self):
-        """Jit-vs-eager fusion rounds the captured activations differently
-        in the last bits, and greedy rounding + layerwise propagation
-        amplify that chaotically — the two runs are equally faithful
-        quantizations, not bitwise twins (measured: ~13.5% output error vs
-        fp for BOTH, ~5% between them).  Parity therefore asserts the
-        functional contract: same modes/report structure, near-identical
-        quantization error against the fp model, and close logits."""
-        outs = []
-        for jit_capture in (True, False):
-            cfg, params, calib, params_q, rep = _quantize(
-                "opt-proxy", jit_capture=jit_capture)
-            outs.append((cfg, params, calib, params_q, rep))
-        assert len(jax.tree_util.tree_leaves(outs[0][3])) \
-            == len(jax.tree_util.tree_leaves(outs[1][3]))
-        assert [l.mode for l in outs[0][4].linears] \
-            == [l.mode for l in outs[1][4].linears]
-        cfg, params, calib = outs[0][0], outs[0][1], outs[0][2]
-        toks = calib[0]["tokens"]
-        lg_fp, _ = T.forward(cfg.model, params, toks)
-        lg_a, _ = T.forward(cfg.model, outs[0][3], toks)
-        lg_b, _ = T.forward(cfg.model, outs[1][3], toks)
-        nrm = float(jnp.linalg.norm(lg_fp))
-        err_a = float(jnp.linalg.norm(lg_a - lg_fp)) / nrm
-        err_b = float(jnp.linalg.norm(lg_b - lg_fp)) / nrm
-        assert abs(err_a - err_b) < 0.02, (err_a, err_b)
-        rel = float(jnp.linalg.norm(lg_a - lg_b)) / nrm
-        assert rel < 0.1, rel
+        """On every layer of the walk, the jitted capture's per-linear
+        Hessians and last inputs match an eager ``Tap`` run of the
+        layer's ``apply_fn`` on the same stream. Jit-vs-eager fusion
+        rounds the bf16 activations differently in the last bits, so the
+        match is relative, within 2 %."""
+        from repro.core import hessian as hess
+        from repro.core import pipeline as qpipe
+        from repro.models.linear import Tap
+
+        cfg = get_config("opt-proxy", smoke=True)
+        mc = cfg.model
+        params = T.init_params(mc, jax.random.PRNGKey(0))
+        calib = calibration_batches(MarkovLM(mc.vocab_size, seed=1), 3, 4,
+                                    24)
+        walker = qpipe._walker_decoder_only(cfg, params, calib)
+        fwd_cache = qpipe.ForwardCache()
+
+        def rel(a, b):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+        assert len(walker.items) == mc.num_layers
+        for step in walker.items:
+            hs = walker.streams[step.hs_slot]
+            cap = qpipe.capture_layer(cfg, step, hs, fwd_cache)
+            layer_params = step.resolve_params()
+            hessians, last_x = {}, {}
+            for bi, h in enumerate(hs):
+                with Tap() as tap:
+                    step.apply_fn(layer_params, h, bi)
+                for name, xs in tap.records.items():
+                    if name not in cap.hessians:
+                        continue
+                    for x in xs:
+                        x2 = x.reshape(-1, x.shape[-1])
+                        st = hessians.get(name,
+                                          hess.init_hessian(x2.shape[1]))
+                        hessians[name] = hess.accumulate(st, x2)
+                        last_x[name] = x2
+            assert hessians.keys() == cap.hessians.keys() \
+                == cap.last_x.keys() and hessians
+            for name, st in hessians.items():
+                assert int(cap.hessians[name].count) == int(st.count)
+                assert rel(cap.hessians[name].H, st.H) < 0.02, name
+                assert rel(cap.last_x[name], last_x[name]) < 0.02, name
+            walker.streams[step.hs_slot] = qpipe.propagate_layer(
+                step, layer_params, hs, fwd_cache)
 
     def test_repeated_layers_reuse_compiled_forward(self):
         """Two same-shape layers: layer 2 adds no new forward entries."""

@@ -141,15 +141,6 @@ class TestOverlapParity:
         _assert_trees_bitwise(packed_s, packed_o, "forced-fallback packed")
         _assert_reports_equal(rep_s, rep_o)
 
-    def test_overlap_with_eager_capture(self):
-        """quant.jit_capture=false disables speculation (eager forwards
-        can't ride the async queue) but overlap still matches serial."""
-        pq_s, rep_s, _ = _run("opt-proxy", "serial", jit_capture=False)
-        pq_o, rep_o, _ = _run("opt-proxy", "overlap", jit_capture=False)
-        assert rep_o.pipeline_stats["spec_captures"] == 0
-        _assert_trees_bitwise(pq_s, pq_o, "eager-capture params")
-        _assert_reports_equal(rep_s, rep_o)
-
     def test_unknown_pipeline_mode_raises(self):
         cfg, params, calib = _fixture("opt-proxy")
         cfg.quant.pipeline = "threaded"

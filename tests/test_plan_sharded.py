@@ -1,7 +1,7 @@
 """Sharded group execution parity on a forced host mesh (DESIGN.md §2.6).
 
 Every test here needs ≥4 host devices, so the plain tier-1 run — which must
-keep the single real CPU device (dry-run contract, tests/conftest.py) —
+keep the single real CPU device (tests/conftest.py) —
 skips the whole file; scripts/check.sh runs it as a dedicated leg under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.  End-to-end
 ``quantize_model`` parity additionally runs as a subprocess check from

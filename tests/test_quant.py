@@ -69,11 +69,13 @@ class TestPacking:
         packed = pack_int4(jnp.asarray(q))
         np.testing.assert_array_equal(np.asarray(unpack_int4(packed)), q)
 
-    def test_pack_quantized_dequant(self):
-        w = _rand((16, 128), 5)
-        qt = pack_quantized(w, 4, 32)
+    @pytest.mark.parametrize("n,k,g", [(16, 128, 32), (64, 256, 128),
+                                       (256, 512, 128), (32, 128, 64)])
+    def test_pack_quantized_dequant(self, n, k, g):
+        w = _rand((n, k), 5)
+        qt = pack_quantized(w, 4, g)
         deq = dequantize_packed(qt)
-        ref = fake_quantize(w, 4, 32)
+        ref = fake_quantize(w, 4, g)
         np.testing.assert_allclose(np.asarray(deq), np.asarray(ref),
                                    atol=1e-5)
 

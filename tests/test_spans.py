@@ -155,15 +155,18 @@ def test_spans_nest_and_close_on_errors(tmp_path):
     assert sa <= ca <= cb <= sb
 
 
-def test_per_linear_executor_has_the_stage_spans(tmp_path):
-    """The legacy per-linear path opens the same stage spans, one pair a
-    linear."""
+@pytest.mark.parametrize("out_dims", [(16, 16), (16, 24)],
+                         ids=["one-group", "two-groups"])
+def test_executor_has_the_stage_spans(tmp_path, out_dims):
+    """The executor opens each stage's input and program spans, and one
+    results span, once a group: two linears of one shape make one group,
+    two shapes make two."""
     cfg = get_config("opt-proxy", smoke=True)
     qc = cfg.quant
-    out_dim, in_dim, n = 16, qc.blocksize, 16
+    in_dim, n = qc.blocksize, 16
     key = jax.random.PRNGKey(3)
     members = []
-    for i in range(2):
+    for i, out_dim in enumerate(out_dims):
         kw, kx = jax.random.split(jax.random.fold_in(key, i))
         x = jax.random.normal(kx, (n, in_dim), jnp.float32)
         h = qplan.hess.accumulate(qplan.hess.init_hessian(in_dim), x)
@@ -171,11 +174,15 @@ def test_per_linear_executor_has_the_stage_spans(tmp_path):
             f"lin{i}", jax.random.normal(kw, (out_dim, in_dim)), h, x,
             x_count=None))
     plan = qplan.build_plan(qc, members)
+    groups = len(set(out_dims))
+    assert len(plan.groups) == groups
     report = qplan.QuantReport()
     with _trace(tmp_path), spans.recording(report.spans):
-        qplan.execute_plan(qc, plan, report, batched=False)
+        qplan.execute_plan(qc, plan, report)
     names = collections.Counter(n for n, _, _ in report.spans)
-    assert names == {spans.STAGE1: 2, spans.STAGE2: 2}
+    assert names == {spans.STAGE1_INPUTS: groups, spans.STAGE1: groups,
+                     spans.STAGE2_INPUTS: groups, spans.STAGE2: groups,
+                     spans.RESULTS: groups}
     assert [r.mode for r in report.linears] == ["rpiq", "rpiq"]
 
 
